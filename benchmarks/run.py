@@ -19,6 +19,10 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma list: fig1,fig2,fig3,fig4,kernels")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         fig1_init,
         fig2_frequencies,

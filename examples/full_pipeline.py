@@ -8,8 +8,14 @@ through the full distributed pipeline.
                                                     [--ingest sync|async]
                                                     [--freq-op dense|structured]
 
+On the CPU, force host devices for the sharded backend (the flag must be set
+before JAX starts):
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python examples/full_pipeline.py --backend sharded
+
 Stages (all from the library, nothing bespoke):
-1. 8 placeholder devices, (4 data x 2 model) mesh;
+1. a (data x 1 model) mesh over every device JAX sees (sharded backend);
 2. the dataset is sketched in ONE pass through the unified SketchEngine —
    backend is a flag: "sharded" (shard_map + psum-merge over the data axis,
    O(m) cross-device traffic), "xla" (chunked scan) or "pallas" (fused
@@ -21,10 +27,6 @@ Stages (all from the library, nothing bespoke):
 5. Lloyd-Max x5 runs on the gathered data as the reference;
 6. wall-clock + quality comparison (paper Fig. 4 protocol, container scale).
 """
-
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
 import time
@@ -44,7 +46,9 @@ from repro.core import (
 from repro.core import available_topologies, ckm, freq_ops, lloyd
 from repro.data import pipeline as pipe
 from repro.data import synthetic
+from repro.launch.mesh import make_local_mesh
 from repro.launch.specs import SketchJobSpec
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -81,6 +85,7 @@ def main():
                          "stacked fast-transform blocks (O(m·sqrt(d)) "
                          "projections, O(1) spec on the wire)")
     args = ap.parse_args()
+    enable_compile_cache()
     job = SketchJobSpec(
         backend=args.backend, reduce_topology=args.topology,
         ingest=args.ingest, ingest_prefetch=args.prefetch,
@@ -105,7 +110,7 @@ def main():
     mesh = None
     xin = x
     if args.backend == "sharded":
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_local_mesh()
     quantizer = ckm.make_quantizer(kf, cfg, m)
     engine = ckm.make_engine(freqs, cfg, mesh, quantizer)
     if args.backend == "sharded":

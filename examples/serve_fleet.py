@@ -66,12 +66,14 @@ from repro.core import CKMConfig, FleetEngine, fleet_specs  # noqa: E402
 from repro.data import synthetic  # noqa: E402
 from repro.launch.specs import SketchJobSpec  # noqa: E402
 from repro.serve.fleet_service import FleetService  # noqa: E402
+from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 K, FEAT = 3, 4
 M = 10 * K * FEAT
 
 
 def main():
+    enable_compile_cache()
     job = SketchJobSpec(
         n_tenants=ARGS.tenants, tenant_shards=ARGS.shards
     ).validate()

@@ -19,6 +19,7 @@ from repro.serve.kv_clustering import (
     attention_decode_compressed,
     build_compressed_cache,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 S_PROMPT = 1024
 N_CENTROIDS = 64
@@ -26,6 +27,7 @@ RING = 64
 
 
 def main():
+    enable_compile_cache()
     cfg = get_smoke_config("llama3.2-1b")
     params = tfm.init_lm(jax.random.PRNGKey(0), cfg)
     dims = tfm.attn_dims(cfg, "attn")

@@ -17,6 +17,7 @@ from repro.configs.base import ShapeConfig, get_config, get_smoke_config
 from repro.data.pipeline import DataConfig
 from repro.launch.mesh import make_local_mesh
 from repro.train.train_loop import LoopConfig, run
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="checkpoints/train_lm")
     ap.add_argument("--full-config", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full_config else get_smoke_config(args.arch)
     shape = ShapeConfig("example", args.seq, args.batch, "train")

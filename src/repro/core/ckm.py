@@ -370,16 +370,20 @@ def decode_sketch(
     keys = jnp.stack(
         [jax.random.fold_in(key, r) for r in range(run_cfg.replicates)]
     )
-    if run_cfg.replicates == 1:
-        out = decode(keys[0], z, w, lower, upper, run_cfg, x_init)
-    elif x_init is None:
-        out = jax.lax.map(
-            lambda k_: decode(k_, z, w, lower, upper, run_cfg), keys
-        )
-    else:
-        out = jax.lax.map(
-            lambda k_: decode(k_, z, w, lower, upper, run_cfg, x_init), keys
-        )
+    # Every decoder contraction (atoms, residuals, NNLS Gram) in f32: on the
+    # TPU a default-precision f32 matmul is a single bf16 pass.
+    with jax.default_matmul_precision("highest"):
+        if run_cfg.replicates == 1:
+            out = decode(keys[0], z, w, lower, upper, run_cfg, x_init)
+        elif x_init is None:
+            out = jax.lax.map(
+                lambda k_: decode(k_, z, w, lower, upper, run_cfg), keys
+            )
+        else:
+            out = jax.lax.map(
+                lambda k_: decode(k_, z, w, lower, upper, run_cfg, x_init),
+                keys,
+            )
     # A tracing decoder returns (cents, alphas, cost, {series}); one with no
     # trace support (or trace off) returns the plain 3-tuple.
     traces = out[3] if len(out) == 4 else None
@@ -446,6 +450,9 @@ def diagnose(result: CKMResult, **kwargs):
 # Evaluation helpers (need data access — used for experiments only)
 # ---------------------------------------------------------------------------
 
+# Exact f32 distances on the TPU too (its default f32 matmul is one bf16 pass).
+_HI = jax.lax.Precision.HIGHEST
+
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def sse(x: jax.Array, centroids: jax.Array, chunk: int = 16384) -> jax.Array:
@@ -464,7 +471,7 @@ def sse(x: jax.Array, centroids: jax.Array, chunk: int = 16384) -> jax.Array:
         xc, vc = inp
         d2 = (
             jnp.sum(xc * xc, axis=1, keepdims=True)
-            - 2.0 * xc @ centroids.T
+            - 2.0 * jnp.matmul(xc, centroids.T, precision=_HI)
             + c2[None, :]
         )
         return acc + jnp.sum(jnp.where(vc, jnp.min(d2, axis=1), 0.0)), None
@@ -498,7 +505,7 @@ def predict(
     def body(_, xc):
         d2 = (
             jnp.sum(xc * xc, axis=1, keepdims=True)
-            - 2.0 * xc @ centroids.T
+            - 2.0 * jnp.matmul(xc, centroids.T, precision=_HI)
             + c2[None, :]
         )
         return None, jnp.argmin(d2, axis=1)

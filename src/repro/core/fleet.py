@@ -53,9 +53,10 @@ dispatch per device, zero cross-shard collectives in the compiled program
 assert that), and per-tenant results stay bitwise equal to the unsharded
 stack and to isolated engines.  ``merge`` and the tenant surgery are
 elementwise/row-wise, so XLA keeps them on the owning shard without an
-explicit shard_map; ``ingest`` scatters land on the owning shard's rows
-(``serve.fleet_service`` routes interleaved requests host-side so each
-dispatch touches one shard's block).  Wire costs of the remaining
+explicit shard_map.  ``ingest`` routes interleaved requests to their owning
+shard on the host and folds each shard's requests on its own device inside
+one shard_map (a Pallas kernel cannot be partitioned by XLA, and no operator
+row leaves its device).  Wire costs of the remaining
 control-plane paths are modeled by ``core.topology.fleet_wire_cost_model``.
 """
 
@@ -66,6 +67,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import engine as eng_mod
 from repro.core import freq_ops as fo
@@ -279,6 +281,7 @@ class FleetEngine:
         self._tenant_sharding = None
         self._mesh_update_jit = None
         self._mesh_finalize_jit = None
+        self._mesh_ingest_jit = {}
         if sharding == "mesh":
             from repro.parallel.sharding import axis_extent, tenant_mesh
 
@@ -763,6 +766,8 @@ class FleetEngine:
                 f"tenant_ids {ids.shape} must be (R,) matching batches "
                 f"{jnp.asarray(batches).shape}"
             )
+        if self.sharding == "mesh":
+            return self._mesh_ingest(state, tenant_ids, batches, weights, t)
         gathered = jax.tree_util.tree_map(
             lambda l: l[ids], self._stacked_op
         )
@@ -800,37 +805,43 @@ class FleetEngine:
         return self._scan_parts(state, ids, parts)
 
     @staticmethod
-    def _scatter_parts(state, ids, parts):
+    def _scatter_parts(state, ids, parts, mode=None):
         """One scatter per leaf.  Sum leaves scatter-add; bounds scatter
         min/max — with unique ids each row sees exactly one partial, so this
-        is the per-tenant merge with no association ambiguity."""
-        add = lambda l, p: l.at[ids].add(p)  # noqa: E731
+        is the per-tenant merge with no association ambiguity.  ``mode``:
+        the scatters' out-of-bounds rule (``"drop"`` skips padding rows)."""
+        add = lambda l, p: l.at[ids].add(p, mode=mode)  # noqa: E731
+        lower = state.lower.at[ids].min(parts.lower, mode=mode)
+        upper = state.upper.at[ids].max(parts.upper, mode=mode)
         if isinstance(state, QuantizedSketchEngineState):
             return QuantizedSketchEngineState(
                 qcos_acc=add(state.qcos_acc, parts.qcos_acc),
                 qsin_acc=add(state.qsin_acc, parts.qsin_acc),
                 weight_sum=add(state.weight_sum, parts.weight_sum),
-                lower=state.lower.at[ids].min(parts.lower),
-                upper=state.upper.at[ids].max(parts.upper),
+                lower=lower,
+                upper=upper,
                 count=add(state.count, parts.count),
             )
         return SketchEngineState(
             cos_acc=add(state.cos_acc, parts.cos_acc),
             sin_acc=add(state.sin_acc, parts.sin_acc),
             weight_sum=add(state.weight_sum, parts.weight_sum),
-            lower=state.lower.at[ids].min(parts.lower),
-            upper=state.upper.at[ids].max(parts.upper),
+            lower=lower,
+            upper=upper,
             count=add(state.count, parts.count),
         )
 
     @staticmethod
-    def _scan_parts(state, ids, parts):
+    def _scan_parts(state, ids, parts, valid=None):
         """Arrival-order fold for duplicate ids: request r merges into its
         tenant's row before request r+1 — float association matches the
-        isolated engine's sequential update exactly."""
+        isolated engine's sequential update exactly.  Requests whose
+        ``valid`` is False (padding) leave every row untouched."""
+        if valid is None:
+            valid = jnp.ones(jnp.shape(ids), bool)
 
         def fold(st, inp):
-            tid, part = inp
+            tid, part, ok = inp
             row = jax.tree_util.tree_map(lambda l: l[tid], st)
             if isinstance(part, eng_mod.DECAYED_STATE_TYPES):
                 stamp = jnp.where(
@@ -841,12 +852,109 @@ class FleetEngine:
                 part = part._replace(stamp=stamp)
             merged = eng_mod._merge_states(row, part)
             st = jax.tree_util.tree_map(
-                lambda l, r: l.at[tid].set(r), st, merged
+                lambda l, m, r: l.at[tid].set(jnp.where(ok, m, r)),
+                st, merged, row,
             )
             return st, None
 
-        state, _ = jax.lax.scan(fold, state, (ids, parts))
+        state, _ = jax.lax.scan(fold, state, (ids, parts, valid))
         return state
+
+    def _mesh_ingest(self, state, tenant_ids, batches, weights, t):
+        """:meth:`ingest` on a mesh-sharded fleet.
+
+        Shard s receives its own requests, in arrival order, padded to the
+        busiest shard's count; padding carries the out-of-block row index
+        ``shard_rows``, which every fold skips.  Each device gathers its
+        requests' operators from its own block and folds them into its own
+        rows: one shard_map, no collective.
+        """
+        if isinstance(tenant_ids, jax.core.Tracer):
+            raise ValueError(
+                "mesh-sharded ingest routes requests to shards on the host; "
+                "tenant_ids must be concrete"
+            )
+        ids = np.asarray(tenant_ids, np.int64)
+        p, rows = self.tenant_shards, self.shard_rows
+        owned = [np.flatnonzero(ids // rows == s) for s in range(p)]
+        width = max(len(o) for o in owned)
+        pick = np.zeros((p, width), np.int64)  # request index per slot
+        local = np.full((p, width), rows, np.int32)  # row in the block
+        for s, o in enumerate(owned):
+            pick[s, : len(o)] = o
+            local[s, : len(o)] = ids[o] - s * rows
+        unique = len(np.unique(ids)) == len(ids)
+
+        def place(a):
+            return jax.device_put(a, self._tenant_sharding)
+
+        x = jnp.asarray(batches, jnp.float32)
+        if self.quantized:
+            if weights is not None:
+                raise ValueError(
+                    "quantized fleet states accumulate unit-weight integer "
+                    "counts; per-point weights are not representable"
+                )
+            aux = self.dither  # (T, m), placed with its tenants
+        elif weights is None:
+            aux = place(jnp.ones(x.shape[:2], jnp.float32)[pick])
+        else:
+            aux = place(jnp.asarray(weights, jnp.float32)[pick])
+        operands = (state, self._stacked_op, place(local), place(x[pick]), aux)
+        if self.decay is not None:
+            # nan = "stamp me with my row's clock", as in the unsharded fold.
+            if t is None:
+                stamps = jnp.full(ids.shape, jnp.nan, jnp.float32)
+            else:
+                stamps = jnp.broadcast_to(
+                    jnp.asarray(t, jnp.float32), ids.shape
+                )
+            operands += (place(stamps[pick]),)
+        return self._mesh_ingest_fn(state, unique)(*operands)
+
+    def _mesh_ingest_fn(self, state, unique: bool):
+        """The shard-mapped fold of :meth:`_mesh_ingest`, built once per
+        engine and id pattern (unique ids scatter; duplicates scan)."""
+        if unique in self._mesh_ingest_jit:
+            return self._mesh_ingest_jit[unique]
+        from repro.utils import compat
+
+        quantized, decayed = self.quantized, self.decay is not None
+        rows = self.shard_rows
+
+        def body(st, op, local, x, aux, *stamps):
+            local, x = local[0], x[0]
+            valid = local < rows
+            safe = jnp.where(valid, local, 0)
+            gathered = jax.tree_util.tree_map(lambda l: l[safe], op)
+            if quantized:
+                parts = jax.vmap(self._tenant_qpart)(gathered, aux[safe], x)
+            else:
+                parts = jax.vmap(self._tenant_part)(gathered, x, aux[0])
+            if decayed:
+                parts = self._lift_parts(parts, stamps[0][0])
+                return self._scan_parts(st, safe, parts, valid)
+            if unique:
+                return self._scatter_parts(st, local, parts, mode="drop")
+            return self._scan_parts(st, safe, parts, valid)
+
+        row = jax.sharding.PartitionSpec(self.tenant_shard_axis)
+        in_specs = (
+            self._row_specs(state),
+            self._row_specs(self._stacked_op),
+            row,
+            row,
+            row,
+        ) + ((row,) if decayed else ())
+        fn = compat.shard_map(
+            body,
+            self.mesh,
+            in_specs=in_specs,
+            out_specs=self._row_specs(state),
+            check_vma=False,
+        )
+        self._mesh_ingest_jit[unique] = jax.jit(fn)
+        return self._mesh_ingest_jit[unique]
 
     def decay_to(self, state, t):
         """Advance every tenant's clock to tick ``t`` (scalar or ``(T,)``)

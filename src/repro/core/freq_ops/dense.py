@@ -39,11 +39,13 @@ class DenseOperator(FrequencyOperator):
     def m(self) -> int:
         return self.w.shape[1]
 
+    # HIGHEST: on the TPU a default-precision f32 matmul is one bf16 pass,
+    # ~0.2 rad of error on phases of tens of radians (same result on CPU).
     def apply(self, x: jax.Array) -> jax.Array:
-        return x @ self.w
+        return jnp.matmul(x, self.w, precision=jax.lax.Precision.HIGHEST)
 
     def adjoint(self, v: jax.Array) -> jax.Array:
-        return v @ self.w.T
+        return jnp.matmul(v, self.w.T, precision=jax.lax.Precision.HIGHEST)
 
     def materialize(self) -> jax.Array:
         return self.w

@@ -173,7 +173,8 @@ def estimate_sigma2(
         key, kf = jax.random.split(key)
         w = draw_frequencies(kf, m0, n, sigma2, dist="adapted_radius")  # (n, m0)
         # Small sketch of the sample (modulus of empirical characteristic fn).
-        proj = x_sample @ w  # (S, m0)
+        # f32-exact on the TPU too (its default f32 matmul is one bf16 pass).
+        proj = jnp.matmul(x_sample, w, precision=jax.lax.Precision.HIGHEST)
         zr = jnp.mean(jnp.cos(proj), axis=0)
         zi = jnp.mean(jnp.sin(proj), axis=0)
         mod = jnp.sqrt(zr**2 + zi**2)  # (m0,)

@@ -63,11 +63,16 @@ def _init_centroids(key, x, lo, hi, cfg: LloydConfig):
     return cents
 
 
+# Exact f32 contractions on the TPU too (its default f32 matmul is one bf16
+# pass, which would round the points' coordinates).
+_HI = jax.lax.Precision.HIGHEST
+
+
 def _assign(x, cents):
     """Nearest-centroid assignment (jnp fallback of the Pallas kernel)."""
     d2 = (
         jnp.sum(x * x, axis=1, keepdims=True)
-        - 2.0 * x @ cents.T
+        - 2.0 * jnp.matmul(x, cents.T, precision=_HI)
         + jnp.sum(cents * cents, axis=1)[None, :]
     )
     return jnp.argmin(d2, axis=1), jnp.min(d2, axis=1)
@@ -97,7 +102,7 @@ def lloyd(key: jax.Array, x: jax.Array, cfg: LloydConfig) -> LloydResult:
         assign, _ = assign_fn(x, cents)
         one_hot = jax.nn.one_hot(assign, cfg.k, dtype=x.dtype)  # (N, K)
         counts = jnp.sum(one_hot, axis=0)  # (K,)
-        sums = one_hot.T @ x  # (K, n)
+        sums = jnp.matmul(one_hot.T, x, precision=_HI)  # (K, n)
         new = jnp.where(
             counts[:, None] > 0, sums / jnp.maximum(counts[:, None], 1.0), cents
         )

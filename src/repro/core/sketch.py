@@ -32,6 +32,9 @@ import jax.numpy as jnp
 from repro.core import freq_ops as fo
 from repro.utils import compat
 
+# f32-exact contractions on the TPU, whose default f32 matmul is one bf16 pass.
+_HI = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "sketch",
     "sketch_quantized",
@@ -99,8 +102,8 @@ def sketch(
         # Accumulators are f32 regardless of the operator's sampling dtype
         # (an f64 operator projects in f64; the cast is a no-op for f32 ops).
         proj = jnp.asarray(op.apply(xc), jnp.float32)  # (chunk, m)
-        c = bc @ jnp.cos(proj)  # (m,)
-        s = bc @ jnp.sin(proj)
+        c = jnp.dot(bc, jnp.cos(proj), precision=_HI)  # (m,)
+        s = jnp.dot(bc, jnp.sin(proj), precision=_HI)
         return (acc[0] + c, acc[1] + s), None
 
     acc0 = jnp.zeros((m,), jnp.float32)
@@ -148,10 +151,19 @@ def sketch_quantized(
     xs = x.reshape(n_chunks, chunk, -1)
     vs = valid.reshape(n_chunks, chunk)
 
+    if isinstance(op, fo.DenseOperator):
+        # The fused kernel's phase arithmetic, so 1-bit codes match it bitwise.
+        from repro.kernels.fourier_sketch import split_matmul
+
+        def project(xc):
+            return split_matmul(xc, op.w.astype(jnp.float32))
+    else:
+        def project(xc):  # f32 phases (see sketch)
+            return jnp.asarray(op.apply(xc), jnp.float32)
+
     def body(acc, inp):
         xc, vc = inp
-        proj = jnp.asarray(op.apply(xc), jnp.float32)  # f32 phases (see sketch)
-        qc, qs = qz.quantize_codes(proj, dither, bits, valid=vc[:, None])
+        qc, qs = qz.quantize_codes(project(xc), dither, bits, valid=vc[:, None])
         return (acc[0] + jnp.sum(qc, axis=0), acc[1] + jnp.sum(qs, axis=0)), None
 
     acc0 = jnp.zeros((m,), jnp.int32)

@@ -24,10 +24,17 @@ def gaussian_mixture(
     n: int,
     c: float = 1.5,
     return_labels: bool = False,
+    means: jax.Array | None = None,
 ):
-    """Draw ``n_points`` from the paper's mixture of K unit Gaussians in R^n."""
+    """Draw ``n_points`` from the paper's mixture of K unit Gaussians in R^n.
+
+    ``means`` (``(k, n)``) fixes the mixture instead of drawing it from
+    ``key``, so a stream of chunks, each drawn under its own key, samples one
+    distribution (``key`` then only drives labels and noise).
+    """
     kmu, kz, kx = jax.random.split(key, 3)
-    means = jax.random.normal(kmu, (k, n)) * jnp.sqrt(c * k ** (1.0 / n))
+    if means is None:
+        means = jax.random.normal(kmu, (k, n)) * jnp.sqrt(c * k ** (1.0 / n))
     labels = jax.random.randint(kz, (n_points,), 0, k)
     x = means[labels] + jax.random.normal(kx, (n_points, n))
     if return_labels:

@@ -24,7 +24,10 @@ def _assign_kernel(x_ref, c_ref, c2_ref, idx_ref, dist_ref):
     c = c_ref[...]  # (K, n)
     # d2(i,k) = ||x_i||^2 - 2 x_i.c_k + ||c_k||^2 ; the x^2 term is constant
     # per-row and irrelevant to the argmin, but needed for the min distance.
-    xc = jnp.dot(x, c.T, preferred_element_type=jnp.float32)  # (bN, K) on MXU
+    xc = jnp.dot(  # (bN, K) on the MXU, f32-exact (see fourier_sketch.py)
+        x, c.T, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     d2 = c2_ref[...] - 2.0 * xc  # (bN, K)
     idx_ref[...] = jnp.argmin(d2, axis=1).astype(jnp.int32)
     x2 = jnp.sum(x * x, axis=1)

@@ -35,6 +35,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def split_matmul(a: jax.Array, b: jax.Array, *, in_kernel: bool = False):
+    """``a @ b`` to f32 accuracy in one single-pass bf16 matmul that a Pallas
+    kernel and XLA compute bitwise alike on the TPU.
+
+    Each operand splits exactly into three bf16 parts (hi + mid + lo); the
+    six cross terms f32 resolves are concatenated along the contraction
+    (each part padded to a multiple of 8, as the kernels pad features) and
+    contracted in one pass with f32 accumulation.  The quantized encoder
+    needs the bitwise agreement: a one-ulp phase difference flips the code
+    of a point on a sign boundary, and two ``precision=HIGHEST`` matmuls,
+    one per compiler, round apart.  Rounding to bf16 is ``astype`` inside a
+    kernel (Mosaic has no ``reduce_precision``) and ``reduce_precision``
+    outside (XLA may drop an ``astype`` round trip as excess precision).
+    """
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def pad8(v, axis):
+        widths = [(0, 0)] * v.ndim
+        widths[axis] = (0, (-v.shape[axis]) % 8)
+        return jnp.pad(v, widths) if widths[axis][1] else v
+
+    if in_kernel:
+        def split(v):
+            hi = v.astype(bf16)
+            r = v - hi.astype(f32)
+            mid = r.astype(bf16)
+            return hi, mid, (r - mid.astype(f32)).astype(bf16)
+    else:
+        def split(v):
+            rp = functools.partial(
+                jax.lax.reduce_precision, exponent_bits=8, mantissa_bits=7
+            )
+            hi = rp(v)
+            mid = rp(v - hi)
+            return hi, mid, rp(v - hi - mid)
+
+    ah, am, al = split(pad8(a, a.ndim - 1))
+    bh, bm, bl = split(pad8(b, 0))
+    lhs = jnp.concatenate([al, ah, am, am, ah, ah], axis=-1).astype(bf16)
+    rhs = jnp.concatenate([bh, bl, bm, bh, bm, bh], axis=0).astype(bf16)
+    return jnp.dot(lhs, rhs, preferred_element_type=f32)
+
+
 def _sketch_kernel(x_ref, w_ref, b_ref, cos_ref, sin_ref):
     """One (bN, bM) tile: proj = x @ w; accumulate beta-weighted cos/sin."""
     j = pl.program_id(1)
@@ -44,8 +87,12 @@ def _sketch_kernel(x_ref, w_ref, b_ref, cos_ref, sin_ref):
         cos_ref[...] = jnp.zeros_like(cos_ref)
         sin_ref[...] = jnp.zeros_like(sin_ref)
 
-    # MXU: (bN, n) @ (n, bM) in f32.
-    proj = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+    # MXU: (bN, n) @ (n, bM).  HIGHEST: the TPU's default f32 matmul is one
+    # bf16 pass, which puts ~0.2 rad of error on phases of tens of radians.
+    proj = jnp.dot(
+        x_ref[...], w_ref[...], precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     beta = b_ref[...]  # (bN, 1)
     # VPU: trig + weighted reduce over the batch tile, all in VMEM.
     cos_ref[...] += jnp.sum(jnp.cos(proj) * beta, axis=0, keepdims=True)
@@ -66,10 +113,8 @@ def _quantized_sketch_kernel(x_ref, w_ref, d_ref, v_ref, qcos_ref, qsin_ref, *, 
         qcos_ref[...] = jnp.zeros_like(qcos_ref)
         qsin_ref[...] = jnp.zeros_like(qsin_ref)
 
-    theta = (
-        jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
-        + d_ref[...]
-    )
+    # MXU: the phases bitwise as core.sketch.sketch_quantized computes them.
+    theta = split_matmul(x_ref[...], w_ref[...], in_kernel=True) + d_ref[...]
     c, s = jnp.cos(theta), jnp.sin(theta)
     if scale == 1:
         qc = jnp.where(c >= 0, 1, -1)

@@ -22,7 +22,10 @@ The fused Pallas kernels mirror ``kernels/fourier_sketch.py``: a grid over
 (frequency blocks, batch tiles) where each tile's projection — here the
 diag/WHT chain instead of an MXU matmul against a dense ``w`` tile — stays in
 VMEM through the trig and the weighted batch reduction, so the ``(N, m)``
-projection never touches HBM.  ``quantized_structured_sketch_kernel`` is the
+projection never touches HBM.  Inside the kernels each WHT stage is one
+``(rows, d) @ H_d`` MXU matmul: the Kronecker form needs a ``(rows, d) ->
+(rows·a, b)`` reshape that splits the lane dimension, which Mosaic cannot
+lower.  ``quantized_structured_sketch_kernel`` is the
 QCKM twin (dithered phases -> int32 code sums).  Off-TPU both run in
 ``interpret=True`` mode (callers in ``kernels/ops.py`` handle dispatch and
 padding).
@@ -65,8 +68,11 @@ def _kron_wht_2d(v: jax.Array, ha: jax.Array, hb: jax.Array) -> jax.Array:
     """(rows, d) -> (H_a ⊗ H_b) applied to each row (d = a·b)."""
     rows = v.shape[0]
     a, b = ha.shape[0], hb.shape[0]
-    y = jnp.dot(v.reshape(rows * a, b), hb, preferred_element_type=v.dtype)
-    y = jnp.einsum("ij,rjk->rik", ha, y.reshape(rows, a, b))
+    hi = jax.lax.Precision.HIGHEST  # f32-exact on the TPU's MXU as well
+    y = jnp.dot(
+        v.reshape(rows * a, b), hb, precision=hi, preferred_element_type=v.dtype
+    )
+    y = jnp.einsum("ij,rjk->rik", ha, y.reshape(rows, a, b), precision=hi)
     return y.reshape(rows, a * b)
 
 
@@ -107,17 +113,19 @@ def hd_chain(xp: jax.Array, diags: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _hd_chain_tile(v, dg, ha, hb, d):
-    """In-VMEM HD chain for one (rows, d) tile; dg: (1, 3, d)."""
+def _hd_chain_tile(v, dg, h, d):
+    """In-VMEM HD chain for one (rows, d) tile; dg: (1, 3, d), h: H_d."""
     c = jnp.asarray(d, v.dtype) ** -0.5
     for s in range(3):
-        v = _kron_wht_2d(v * dg[0, s, :][None, :], ha, hb) * c
+        v = jnp.dot(
+            v * dg[0, s, :][None, :], h,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        ) * c
     return v
 
 
-def _structured_sketch_kernel(
-    x_ref, dg_ref, r_ref, ha_ref, hb_ref, b_ref, cos_ref, sin_ref
-):
+def _structured_sketch_kernel(x_ref, dg_ref, r_ref, h_ref, b_ref, cos_ref, sin_ref):
     """One (bN, d) tile: WHT-chain projection; accumulate weighted cos/sin."""
     j = pl.program_id(1)
 
@@ -127,7 +135,7 @@ def _structured_sketch_kernel(
         sin_ref[...] = jnp.zeros_like(sin_ref)
 
     d = x_ref.shape[-1]
-    v = _hd_chain_tile(x_ref[...], dg_ref[...], ha_ref[...], hb_ref[...], d)
+    v = _hd_chain_tile(x_ref[...], dg_ref[...], h_ref[...], d)
     proj = v * r_ref[...]  # (bN, d) * (1, d) — radial rescaling
     beta = b_ref[...]  # (bN, 1)
     cos_ref[...] += jnp.sum(jnp.cos(proj) * beta, axis=0, keepdims=True)
@@ -135,7 +143,7 @@ def _structured_sketch_kernel(
 
 
 def _quantized_structured_sketch_kernel(
-    x_ref, dg_ref, r_ref, dth_ref, ha_ref, hb_ref, v_ref, qcos_ref, qsin_ref,
+    x_ref, dg_ref, r_ref, dth_ref, h_ref, v_ref, qcos_ref, qsin_ref,
     *, scale,
 ):
     """QCKM twin: dithered WHT-chain phases -> int32 code sums in VMEM."""
@@ -147,7 +155,7 @@ def _quantized_structured_sketch_kernel(
         qsin_ref[...] = jnp.zeros_like(qsin_ref)
 
     d = x_ref.shape[-1]
-    v = _hd_chain_tile(x_ref[...], dg_ref[...], ha_ref[...], hb_ref[...], d)
+    v = _hd_chain_tile(x_ref[...], dg_ref[...], h_ref[...], d)
     theta = v * r_ref[...] + dth_ref[...]
     c, s = jnp.cos(theta), jnp.sin(theta)
     if scale == 1:
@@ -161,17 +169,24 @@ def _quantized_structured_sketch_kernel(
     qsin_ref[...] += jnp.sum(qs.astype(jnp.int32) * valid, axis=0, keepdims=True)
 
 
-def _specs(nblocks, d, block_n, a, b, extra_freq_rows=0):
-    """Shared in_specs for (x, diags, radii[, dither], ha, hb, per-row)."""
+# One frequency block's row of a per-block ``(nblocks, 1, d)`` array, seen by
+# the kernel as ``(1, d)``.  The block's last two dims equal the array's, as
+# the TPU lowering requires (a ``(1, d)`` block of ``(nblocks, d)`` is not
+# (8, 128)-aligned).
+def _freq_row(d):
+    return pl.BlockSpec((None, 1, d), lambda i, j: (i, 0, 0))
+
+
+def _specs(d, block_n, extra_freq_rows=0):
+    """Shared in_specs for (x, diags, radii[, dither], H_d, per-row)."""
     specs = [
         pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
         pl.BlockSpec((1, 3, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, d), lambda i, j: (i, 0)),
+        _freq_row(d),
     ]
-    specs += [pl.BlockSpec((1, d), lambda i, j: (i, 0))] * extra_freq_rows
+    specs += [_freq_row(d)] * extra_freq_rows
     specs += [
-        pl.BlockSpec((a, a), lambda i, j: (0, 0)),
-        pl.BlockSpec((b, b), lambda i, j: (0, 0)),
+        pl.BlockSpec((d, d), lambda i, j: (0, 0)),
         pl.BlockSpec((block_n, 1), lambda i, j: (j, 0)),
     ]
     return specs
@@ -195,22 +210,19 @@ def structured_sketch_kernel(
     n_pts, d = x.shape
     nblocks = diags.shape[0]
     assert n_pts % block_n == 0, (n_pts, block_n)
-    a, b = kron_factors(d)
     grid = (nblocks, n_pts // block_n)
-    return pl.pallas_call(
+    cos_s, sin_s = pl.pallas_call(
         _structured_sketch_kernel,
         grid=grid,
-        in_specs=_specs(nblocks, d, block_n, a, b),
-        out_specs=[
-            pl.BlockSpec((1, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, d), lambda i, j: (i, 0)),
-        ],
+        in_specs=_specs(d, block_n),
+        out_specs=[_freq_row(d), _freq_row(d)],
         out_shape=[
-            jax.ShapeDtypeStruct((nblocks, d), jnp.float32),
-            jax.ShapeDtypeStruct((nblocks, d), jnp.float32),
+            jax.ShapeDtypeStruct((nblocks, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((nblocks, 1, d), jnp.float32),
         ],
         interpret=interpret,
-    )(x, diags, radii, hadamard(a), hadamard(b), beta)
+    )(x, diags, radii.reshape(nblocks, 1, d), hadamard(d), beta)
+    return cos_s.reshape(nblocks, d), sin_s.reshape(nblocks, d)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_n", "interpret"))
@@ -228,19 +240,17 @@ def quantized_structured_sketch_kernel(
     n_pts, d = x.shape
     nblocks = diags.shape[0]
     assert n_pts % block_n == 0, (n_pts, block_n)
-    a, b = kron_factors(d)
     grid = (nblocks, n_pts // block_n)
-    return pl.pallas_call(
+    rows = lambda v: v.reshape(nblocks, 1, d)  # noqa: E731
+    qcos, qsin = pl.pallas_call(
         functools.partial(_quantized_structured_sketch_kernel, scale=scale),
         grid=grid,
-        in_specs=_specs(nblocks, d, block_n, a, b, extra_freq_rows=1),
-        out_specs=[
-            pl.BlockSpec((1, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, d), lambda i, j: (i, 0)),
-        ],
+        in_specs=_specs(d, block_n, extra_freq_rows=1),
+        out_specs=[_freq_row(d), _freq_row(d)],
         out_shape=[
-            jax.ShapeDtypeStruct((nblocks, d), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, d), jnp.int32),
+            jax.ShapeDtypeStruct((nblocks, 1, d), jnp.int32),
+            jax.ShapeDtypeStruct((nblocks, 1, d), jnp.int32),
         ],
         interpret=interpret,
-    )(x, diags, radii, dither, hadamard(a), hadamard(b), valid)
+    )(x, diags, rows(radii), rows(dither), hadamard(d), valid)
+    return qcos.reshape(nblocks, d), qsin.reshape(nblocks, d)

@@ -72,6 +72,13 @@ def _as_op(w):
     return w
 
 
+def _structured_block_n(block_n, op):
+    """Batch tile of the structured kernels: at most 2^17 elements per
+    ``(block_n, d)`` tile, so the tile, ``H_d`` and the chain's temporaries
+    stay inside the kernel's VMEM at wide blocks (d = 512 and up)."""
+    return min(block_n, max(8, (1 << 17) // op.d))
+
+
 def _structured_pad(x, op, block_n):
     """Pad a batch for the structured kernels: N to block, n to the WHT width
     (zero feature columns shift no phases — the operator itself zero-pads)."""
@@ -112,6 +119,7 @@ def fourier_sketch_sums(
     if isinstance(op, freq_ops.StructuredOperator):
         from repro.kernels import freq_transform as _ft
 
+        block_n = _structured_block_n(block_n, op)
         xp = _structured_pad(x, op, block_n)
         beta_p = _pad_to(beta, 0, block_n)  # zero-weight rows are no-ops
         cos_s, sin_s = _ft.structured_sketch_kernel(
@@ -181,6 +189,7 @@ def quantized_fourier_sketch_sums(
     if isinstance(op, freq_ops.StructuredOperator):
         from repro.kernels import freq_transform as _ft
 
+        block_n = _structured_block_n(block_n, op)
         xp = _structured_pad(x, op, block_n)
         valid_p = _pad_to(valid, 0, block_n)  # valid=0 rows -> zero codes
         # Dither padded to the block tail with zeros (tail codes sliced off).
@@ -286,7 +295,8 @@ def sketch_shift_scores(
     if impl == "xla":
         proj = jnp.asarray(op.apply(c), jnp.float32)  # (P, m)
         cosp, sinp = jnp.cos(proj), jnp.sin(proj)
-        f = (cosp @ z1 - sinp @ z2) / m
+        hi = jax.lax.Precision.HIGHEST
+        f = (jnp.dot(cosp, z1, precision=hi) - jnp.dot(sinp, z2, precision=hi)) / m
         g = jnp.asarray(
             op.adjoint((-sinp) * z1[None, :] - cosp * z2[None, :]), jnp.float32
         ) / m
@@ -347,37 +357,14 @@ def amp_denoise(
     q = jnp.maximum(jnp.asarray(q, jnp.float32).reshape(()), 1e-20)
     lo = jnp.broadcast_to(jnp.asarray(lower, jnp.float32), (feat,))
     hi = jnp.broadcast_to(jnp.asarray(upper, jnp.float32), (feat,))
+    from repro.kernels import amp_denoise as _amp
+
     if impl == "xla":
-        sig = jnp.sqrt(q)
-        a = (lo[None, :] - r) / sig
-        b = (hi[None, :] - r) / sig
-        inv_sqrt2pi = 0.3989422804014327
-        pa = inv_sqrt2pi * jnp.exp(-0.5 * a * a)
-        pb = inv_sqrt2pi * jnp.exp(-0.5 * b * b)
-        # Tail-stable Phi(b) - Phi(a) via erfc (see kernels/amp_denoise.py).
-        inv_sqrt2 = 0.7071067811865476
-        z_mass = 0.5 * jnp.where(
-            a + b > 0,
-            jax.lax.erfc(a * inv_sqrt2) - jax.lax.erfc(b * inv_sqrt2),
-            jax.lax.erfc(-b * inv_sqrt2) - jax.lax.erfc(-a * inv_sqrt2),
-        )
-        z_mass = jnp.maximum(z_mass, 1e-30)
-        inside = z_mass > 1e-12
-        apa = jnp.where(jnp.isfinite(a), a * pa, 0.0)
-        bpb = jnp.where(jnp.isfinite(b), b * pb, 0.0)
-        frac = (pa - pb) / z_mass
-        mean = r + sig * frac
-        var = q * (1.0 + (apa - bpb) / z_mass - frac * frac)
-        mean = jnp.where(inside, mean, jnp.clip(r, lo[None, :], hi[None, :]))
-        var = jnp.where(inside, var, q * 1e-6)
-        return (
-            jnp.clip(mean, lo[None, :], hi[None, :]),
-            jnp.clip(var, q * 1e-12, q),
+        return _amp.truncated_normal_moments(
+            r, q, lo[None, :], hi[None, :], erfc_fn=jax.lax.erfc
         )
     if interpret is None:
         interpret = _on_cpu()
-    from repro.kernels import amp_denoise as _amp
-
     block_k = min(block_k, max(8, 1 << (k_est - 1).bit_length()))
     # Pad: K to block (garbage rows sliced off), n to the lane width with
     # benign cells (r=0 inside a [-1, 1] box at unit variance cannot produce

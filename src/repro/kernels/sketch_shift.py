@@ -46,8 +46,11 @@ def _shift_kernel(c_ref, w_ref, z1_ref, z2_ref, f_ref, g_ref):
         f_ref[...] = jnp.zeros_like(f_ref)
         g_ref[...] = jnp.zeros_like(g_ref)
 
-    # MXU: (bP, n) @ (n, bM) in f32.
-    proj = jnp.dot(c_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+    # MXU: (bP, n) @ (n, bM), f32-exact (see fourier_sketch.py).
+    proj = jnp.dot(
+        c_ref[...], w_ref[...], precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     z1 = z1_ref[...]  # (1, bM)
     z2 = z2_ref[...]
     cosp = jnp.cos(proj)
@@ -57,7 +60,8 @@ def _shift_kernel(c_ref, w_ref, z1_ref, z2_ref, f_ref, g_ref):
     # MXU: gradient contraction of the combined tile against W^T.
     t = -sinp * z1 - cosp * z2  # (bP, bM)
     g_ref[...] += jnp.dot(
-        t, w_ref[...].T, preferred_element_type=jnp.float32
+        t, w_ref[...].T, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
 

@@ -96,7 +96,7 @@ def tenant_mesh(shards: int, axis: str = "tenant", devices=None) -> Mesh:
     is pure data parallelism, so a single axis is always enough; the axis
     name defaults to ``SketchJobSpec.tenant_shard_axis``'s default.
     """
-    import numpy as np
+    from repro.launch.mesh import make_mesh
 
     if shards < 1:
         raise ValueError(f"shards must be >= 1, got {shards}")
@@ -108,7 +108,7 @@ def tenant_mesh(shards: int, axis: str = "tenant", devices=None) -> Mesh:
             "XLA_FLAGS=--xla_force_host_platform_device_count=N before "
             "jax initialises)"
         )
-    return Mesh(np.asarray(devices[:shards]), (axis,))
+    return make_mesh((shards,), (axis,), devices=devices[:shards])
 
 
 def tenant_shard_specs(tree: Any, axis: str = "tenant") -> Any:

@@ -1,14 +1,9 @@
-"""JAX version-compat shims.
+"""``shard_map`` / ``pvary`` entry points.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the top-level
-``jax.shard_map`` (and its kwargs were renamed: ``check_rep``/``auto`` became
-``check_vma``/``axis_names``).  Similarly ``jax.lax.pcast`` (marking a value
-as varying over manual mesh axes) only exists on newer JAX; on older versions
-replication tracking is disabled instead, so the cast is a no-op.
-
-Every module in this repo that needs shard_map goes through this shim — the
-call sites use the NEW spelling (``axis_names=...``) and this module translates
-for whichever JAX is installed.
+Every module in this repo that needs ``shard_map`` goes through this module
+(never ``jax.shard_map`` directly), so the keyword conventions live in one
+place: ``axis_names`` (the manual axes) and ``check_vma`` are forwarded only
+when given, and ``pvary`` accepts a single axis name or a sequence.
 """
 
 from __future__ import annotations
@@ -19,8 +14,6 @@ import jax
 
 __all__ = ["shard_map", "pvary"]
 
-_HAS_NATIVE = hasattr(jax, "shard_map")
-
 
 def shard_map(
     f: Callable,
@@ -30,56 +23,30 @@ def shard_map(
     axis_names: set | frozenset | None = None,
     check_vma: bool | None = None,
 ):
-    """Version-portable ``shard_map``.
+    """``jax.shard_map``.
 
     ``axis_names``: mesh axes the body is *manual* over (None = all axes).
-    ``check_vma``: varying-manual-axes checking; ignored (forced off) on JAX
-    versions whose replication checker predates ``pvary``/``pcast`` semantics,
-    where bodies written for the new rules would be rejected spuriously.
+    ``check_vma``: varying-manual-axes checking (None = JAX's default).
     """
-    if _HAS_NATIVE:
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
+    kwargs = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    fn = _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=False,
-        auto=auto,
+        kwargs["axis_names"] = set(axis_names)
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
-    if auto:
-        # The pre-0.5 eager impl raises NotImplementedError for partial-manual
-        # (auto) meshes; the jit path handles it, so force tracing.
-        fn = jax.jit(fn)
-    return fn
 
 
 def pvary(x, axis_names) -> jax.Array:
-    """Mark ``x`` as varying over manual ``axis_names`` (no-op on old JAX).
+    """Mark ``x`` as varying over manual ``axis_names`` (``jax.lax.pcast``).
 
-    Newer JAX tracks varying-manual-axes (VMA) types inside shard_map and
-    requires scan carries etc. to be explicitly cast with ``jax.lax.pcast``;
-    older versions have no such type, so the identity is the correct shim.
+    Scan carries inside ``shard_map`` must be cast explicitly under the
+    varying-manual-axes type rules; an empty ``axis_names`` is the identity.
     """
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
     axis_names = tuple(axis_names)
     if not axis_names:
         return x
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_names, to="varying")
-    return x
+    return jax.lax.pcast(x, axis_names, to="varying")

@@ -4,9 +4,11 @@ All quantities are PER-DEVICE: the compiled module of an SPMD program is the
 per-device program, so ``cost_analysis()`` flops/bytes and the collective
 bytes parsed from ``compiled.as_text()`` are per-chip numbers.
 
-    compute_s    = HLO_flops / peak_flops            (197 TFLOP/s bf16, v5e)
-    memory_s     = HLO_bytes / hbm_bw                (819 GB/s)
-    collective_s = collective_bytes / link_bw        (~50 GB/s/link ICI)
+    compute_s    = HLO_flops / peak_flops
+    memory_s     = HLO_bytes / hbm_bw
+    collective_s = collective_bytes / link_bw
+
+with the peaks of the chip named by its ``device_kind`` (:data:`PEAKS`).
 
 The dominant term is the step-time lower bound; MODEL_FLOPS/HLO_FLOPs
 measures how much compiled compute is "useful" (remat/dispatch waste).
@@ -17,9 +19,32 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-LINK_BW = 50e9  # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    link_bw: float  # bytes/s per ICI link
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of ICI over
+# four links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of one chip; a kind not in :data:`PEAKS` raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -84,6 +109,7 @@ class Roofline:
     hbm_bytes: float  # per device
     collective_bytes: float  # per device
     chips: int
+    peaks: ChipPeaks
     compute_s: float
     memory_s: float
     collective_s: float
@@ -96,13 +122,15 @@ class Roofline:
 
     def roofline_fraction(self) -> float:
         """useful-compute time / bound step time (the score axis)."""
-        t_useful = self.model_flops / self.chips / PEAK_FLOPS
+        t_useful = self.model_flops / self.chips / self.peaks.flops
         b = self.bound_step_time()
         return t_useful / b if b > 0 else 0.0
 
 
-def analyze(compiled, chips: int, model_flops: float) -> Roofline:
-    """Roofline terms from the compiled artifact.
+def analyze(
+    compiled, chips: int, model_flops: float, device_kind: str
+) -> Roofline:
+    """Roofline terms from the compiled artifact, on ``device_kind`` chips.
 
     Uses the trip-count-aware HLO analyzer (utils/hlo.py): XLA's own
     ``cost_analysis()`` counts scan bodies once, which would undercount every
@@ -117,9 +145,10 @@ def analyze(compiled, chips: int, model_flops: float) -> Roofline:
         dict(costs.coll_by_op),
         {k: int(v) for k, v in costs.coll_count.items()},
     )
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
-    collective_s = coll.total_bytes / LINK_BW
+    chip = peaks(device_kind)
+    compute_s = flops / chip.flops
+    memory_s = hbm / chip.hbm_bw
+    collective_s = coll.total_bytes / chip.link_bw
     dominant = max(
         [("compute", compute_s), ("memory", memory_s), ("collective", collective_s)],
         key=lambda kv: kv[1],
@@ -129,6 +158,7 @@ def analyze(compiled, chips: int, model_flops: float) -> Roofline:
         hbm_bytes=hbm,
         collective_bytes=float(coll.total_bytes),
         chips=chips,
+        peaks=chip,
         compute_s=compute_s,
         memory_s=memory_s,
         collective_s=collective_s,

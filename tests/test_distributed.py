@@ -60,7 +60,8 @@ _SUBPROC = textwrap.dedent(
     x = jax.random.normal(kx, (4096, 6))
     w = fq.draw_frequencies(kw, 32, 6, 1.0)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     xs = ds.shard_points(x, mesh, ("data",))
     z, lo, hi = ds.sharded_sketch(xs, w, mesh, ("data",), chunk=512)
     z_ref = sk.sketch(x, w)
@@ -69,7 +70,7 @@ _SUBPROC = textwrap.dedent(
     np.testing.assert_allclose(np.asarray(hi), np.asarray(x.max(0)), atol=1e-6)
 
     # pod x data mesh: merge across both axes.
-    mesh2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     xs2 = ds.shard_points(x, mesh2, ("pod", "data"))
     z2, lo2, hi2 = ds.sharded_sketch(xs2, w, mesh2, ("pod", "data"), chunk=512)
     np.testing.assert_allclose(np.asarray(z2), np.asarray(z_ref), atol=1e-5)

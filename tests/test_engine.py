@@ -103,7 +103,8 @@ class TestZeroWeightFinalize:
             if quantized
             else None
         )
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
         return {
             "xla": eng_mod.SketchEngine(w, "xla", quantizer=q),
             "pallas": eng_mod.SketchEngine(
@@ -172,7 +173,8 @@ class TestBackendParity:
             w = fq.draw_frequencies(kw, 48, 6, 1.0)
             z_ref = np.asarray(sk.sketch(x, w))
 
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             engines = {
                 "xla": eng_mod.SketchEngine(w, "xla", chunk=512),
                 "pallas": eng_mod.SketchEngine(w, "pallas", block_n=512,

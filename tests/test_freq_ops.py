@@ -163,7 +163,8 @@ class TestDenseBitwiseIdentity:
             kx, kf = jax.random.split(key)
             x = jax.random.normal(kx, (4096, 6))
             op = fo.make_operator("dense", kf, 48, 6, 1.0)
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             z_raw, lo_r, hi_r = eng_mod.SketchEngine(
                 op.w, "sharded", mesh=mesh, chunk=512).sketch(x)
             z_op, lo_o, hi_o = eng_mod.SketchEngine(
@@ -363,7 +364,7 @@ class TestDtypeSatellite:
     def test_draw_frequencies_dtype(self):
         w32 = fq.draw_frequencies(jax.random.PRNGKey(0), 16, 4, 1.0)
         assert w32.dtype == jnp.float32
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             w64 = fq.draw_frequencies(
                 jax.random.PRNGKey(0), 16, 4, 1.0, dtype=jnp.float64
             )
@@ -375,7 +376,7 @@ class TestDtypeSatellite:
         u = np.linspace(0.005, 0.995, 199)
         for sigma2 in (0.25, 1.0, 9.0):
             r32 = np.asarray(fq.radius_from_uniform(u, sigma2, jnp.float32))
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 r64 = np.asarray(
                     fq.radius_from_uniform(u, sigma2, jnp.float64)
                 )
@@ -393,7 +394,7 @@ class TestDtypeSatellite:
         """An f64 operator projects in f64 but the sketch/decoder pipeline
         keeps its f32 accumulator contract — the advertised
         ``freq_dtype="float64"`` path must actually fit."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = jax.random.normal(jax.random.PRNGKey(0), (256, 3), jnp.float32)
             cfg = ckm_mod.CKMConfig(
                 k=2, m=24, sigma2=1.0, freq_op=freq_op, freq_dtype="float64",

@@ -97,7 +97,8 @@ class TestHloAnalyzer:
             from repro.utils import hlo
             from repro.utils.compat import shard_map
 
-            mesh = jax.make_mesh((4,), ("d",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4,), ("d",))
             steps, n = 6, 1024
 
             def f(x):

@@ -73,6 +73,32 @@ class TestFourierSketchKernel:
         np.testing.assert_allclose(np.asarray(z[:128]), np.asarray(cos_ref), atol=atol)
 
 
+class TestSplitMatmul:
+    """The quantized encoder's phase matmul: f32-accurate from one bf16
+    pass, and the in-kernel and XLA roundings give the same bits."""
+
+    @pytest.mark.parametrize("feat", [1, 10, 16])
+    @pytest.mark.parametrize("in_kernel", [False, True])
+    def test_f32_accurate(self, feat, in_kernel):
+        from repro.kernels.fourier_sketch import split_matmul
+
+        x, w, _ = _data(4, 512, feat, 200)
+        got = np.asarray(jax.jit(
+            lambda a, b: split_matmul(a, b, in_kernel=in_kernel)
+        )(x, w), np.float64)
+        want = np.asarray(x, np.float64) @ np.asarray(w, np.float64)
+        # f32 rounding of |phases| up to ~30 rad, far below a bf16 pass's.
+        assert np.max(np.abs(got - want)) < 2e-5
+
+    def test_kernel_and_xla_roundings_agree_bitwise(self):
+        from repro.kernels.fourier_sketch import split_matmul
+
+        x, w, _ = _data(5, 512, 10, 200)
+        a = jax.jit(lambda a, b: split_matmul(a, b))(x, w)
+        b = jax.jit(lambda a, b: split_matmul(a, b, in_kernel=True))(x, w)
+        assert bool(jnp.array_equal(a, b))
+
+
 class TestAssignArgminKernel:
     @pytest.mark.parametrize(
         "n_pts,feat,k",

@@ -12,7 +12,8 @@ _PROG = textwrap.dedent(
     import jax, jax.numpy as jnp, numpy as np
     from repro.parallel.pipeline import pipeline_apply, bubble_fraction
 
-    mesh = jax.make_mesh((4,), ("pipe",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("pipe",))
     n_stages, n_micro, mb, d = 4, 8, 2, 16
     key = jax.random.PRNGKey(0)
     kw, kx = jax.random.split(key)

@@ -212,7 +212,8 @@ class TestQuantizedBackendParity:
             x = jax.random.normal(kx, (4096, 6))
             w = fq.draw_frequencies(kw, 48, 6, 1.0)
             q = qz.SketchQuantizer(1, qz.draw_dither(kd, 48))
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             e_x = eng_mod.SketchEngine(w, "xla", chunk=512, quantizer=q)
             e_s = eng_mod.SketchEngine(w, "sharded", mesh=mesh, chunk=512,
                                        quantizer=q)

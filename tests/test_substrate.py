@@ -235,7 +235,8 @@ class TestGradCompression:
                 compress_allreduce_tree, init_error_state)
             from repro.utils.compat import shard_map
 
-            mesh = jax.make_mesh((2, 2), ("pod", "data"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 2), ("pod", "data"))
             n = 4096
             key = jax.random.PRNGKey(0)
             g_pods = jax.random.normal(key, (2, n))  # one grad per pod

@@ -175,7 +175,8 @@ class TestShardedTopologies:
             x = jax.random.normal(kx, (4096, 6))
             w = fq.draw_frequencies(kw, 48, 6, 1.0)
             z_ref = np.asarray(sk.sketch(x, w))
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
 
             for name in ("allreduce", "tree", "ring"):
                 e = eng_mod.SketchEngine(w, "sharded", mesh=mesh, chunk=512,
@@ -231,7 +232,8 @@ class TestShardedTopologies:
             from repro.core import frequencies as fq
 
             w = fq.draw_frequencies(jax.random.PRNGKey(0), 16, 4, 1.0)
-            mesh = jax.make_mesh((3, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((3, 2), ("data", "model"))
             e = eng_mod.SketchEngine(w, "sharded", mesh=mesh,
                                      reduce_topology="tree")
             try:

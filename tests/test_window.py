@@ -74,7 +74,8 @@ def _engines(quant="none", decay=GAMMA, m=24):
         if quant != "none"
         else None
     )
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     return {
         "xla": eng_mod.SketchEngine(w, "xla", quantizer=q, decay=decay),
         "pallas": eng_mod.SketchEngine(
