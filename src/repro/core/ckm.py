@@ -47,6 +47,7 @@ from repro.core import quantize as qz
 from repro.core import sketch as sk
 from repro.core.decoders import AMPConfig, CLOMPRConfig, SketchShiftConfig
 from repro.core.engine import SketchEngine
+from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,9 +138,10 @@ class CKMConfig:
     # config, so the decode also returns its per-iteration trajectory
     # (CLOMPR/sketch_shift: residual norms; amp: unexplained energy +
     # posterior variance).  ``decode_sketch`` emits the selected replicate's
-    # series through ``repro.obs.trace`` — and flips this flag on by itself
-    # when telemetry is enabled (host-side calls only; the traced buffers
-    # are dead-code-eliminated whenever the flag is off).
+    # series through ``repro.obs.trace`` when telemetry is enabled.  This
+    # flag alone decides it: telemetry never switches it on, since a tracing
+    # decoder is another program (the traced buffers are
+    # dead-code-eliminated whenever the flag is off).
     trace_convergence: bool = False
 
     def sketch_size(self, n: int) -> int:
@@ -252,18 +254,31 @@ def _draw_freqs(key, sample: jax.Array, n: int, cfg: CKMConfig):
     builder calls ``frequencies.draw_frequencies`` with the same key — the
     registry path is bitwise-identical to the historical direct draw).  The
     sigma2/frequency keys come from the shared :func:`stream_keys` fan-out.
+    Step 1 runs under the ``ckm.sigma2`` span, step 2 under ``ckm.operator``.
     """
     k_sig, k_freq, _ = stream_keys(key)
-    if cfg.sigma2 is None:
-        take = min(cfg.sigma2_sample, sample.shape[0])
-        sigma2 = freq_mod.estimate_sigma2(k_sig, sample[:take])
-    else:
-        sigma2 = jnp.asarray(cfg.sigma2, jnp.float32)
-    op = fo.make_operator(
-        cfg.freq_op, k_freq, cfg.sketch_size(n), n, sigma2,
-        dist=cfg.freq_dist, dtype=jnp.dtype(cfg.freq_dtype),
-    )
+    with obs_trace.span("ckm.sigma2"):
+        if cfg.sigma2 is None:
+            take = min(cfg.sigma2_sample, sample.shape[0])
+            sigma2 = freq_mod.estimate_sigma2(k_sig, sample[:take])
+        else:
+            sigma2 = jnp.asarray(cfg.sigma2, jnp.float32)
+    with obs_trace.span("ckm.operator", freq_op=cfg.freq_op):
+        op = fo.make_operator(
+            cfg.freq_op, k_freq, cfg.sketch_size(n), n, sigma2,
+            dist=cfg.freq_dist, dtype=jnp.dtype(cfg.freq_dtype),
+        )
     return op, sigma2
+
+
+def _sketch_engine(key, sample: jax.Array, cfg: CKMConfig, mesh):
+    """Steps 1–2 on ``sample`` and the engine that runs step 3 on their
+    operator: ``(engine, operator, sigma2)``.  The quantizer and the engine
+    are built under a second ``ckm.operator`` span."""
+    op, sigma2 = _draw_freqs(key, sample, sample.shape[1], cfg)
+    with obs_trace.span("ckm.operator", freq_op=cfg.freq_op):
+        eng = make_engine(op, cfg, mesh, make_quantizer(key, cfg, op.m))
+    return eng, op, sigma2
 
 
 def compute_sketch(
@@ -277,9 +292,9 @@ def compute_sketch(
     ``op.materialize()`` recovers the dense matrix when needed.
     """
     x = jnp.asarray(x, jnp.float32)
-    op, sigma2 = _draw_freqs(key, x, x.shape[1], cfg)
-    quantizer = make_quantizer(key, cfg, op.m)
-    z, lo, hi = make_engine(op, cfg, mesh, quantizer).sketch(x)
+    eng, op, sigma2 = _sketch_engine(key, x, cfg, mesh)
+    with obs_trace.span("ckm.ingest", chunk=0):
+        z, lo, hi = eng.sketch(x)
     return z, op, sigma2, (lo, hi)
 
 
@@ -292,6 +307,8 @@ def compute_sketch_streaming(
     uses "a small fraction of the data"); every batch — the first included —
     is then folded into the engine state.  Returns the first batch as the
     last element so callers may reuse it for sample/kpp decoder inits.
+    Batch i is folded under a ``ckm.ingest`` span with ``chunk=i`` (the
+    async path folds batches 1 onward under one, ``ingest="async"``).
     """
     if cfg.ingest not in ("sync", "async"):
         raise ValueError(
@@ -302,27 +319,29 @@ def compute_sketch_streaming(
         first = jnp.asarray(next(it), jnp.float32)
     except StopIteration:
         raise ValueError("compute_sketch_streaming needs at least one batch")
-    op, sigma2 = _draw_freqs(key, first, first.shape[1], cfg)
-    quantizer = make_quantizer(key, cfg, op.m)
-    eng = make_engine(op, cfg, mesh, quantizer)
-    state = eng.update(eng.init_state(), first)
+    eng, op, sigma2 = _sketch_engine(key, first, cfg, mesh)
+    with obs_trace.span("ckm.ingest", chunk=0):
+        state = eng.update(eng.init_state(), first)
     if cfg.ingest == "async":
         # Overlap production/transfer of the remaining batches with sketch
         # compute (core.ingest).  Same batches, same order -> same result.
         from repro.core import ingest as ingest_mod
 
-        state, _ = ingest_mod.ingest_stream(
-            eng, it, state=state, prefetch=cfg.ingest_prefetch
-        )
+        with obs_trace.span("ckm.ingest", chunk=1, ingest="async"):
+            state, _ = ingest_mod.ingest_stream(
+                eng, it, state=state, prefetch=cfg.ingest_prefetch
+            )
     else:
-        for batch in it:
-            state = eng.update(state, batch)
-            # Strict streaming backpressure: the batch may be discarded the
-            # moment it is folded in (the O(m)-memory contract).  Without
-            # this, async dispatch would buffer every pending batch whenever
-            # the source outruns compute.  ingest="async" relaxes it to a
-            # bounded double buffer (core.ingest) to overlap the two.
-            jax.block_until_ready(state)
+        for i, batch in enumerate(it, start=1):
+            with obs_trace.span("ckm.ingest", chunk=i):
+                state = eng.update(state, batch)
+                # Strict streaming backpressure: the batch may be discarded
+                # the moment it is folded in (the O(m)-memory contract).
+                # Without this, async dispatch would buffer every pending
+                # batch whenever the source outruns compute.  ingest="async"
+                # relaxes it to a bounded double buffer (core.ingest) to
+                # overlap the two.
+                jax.block_until_ready(state)
     z, lo, hi = eng.finalize(state)
     return z, op, sigma2, (lo, hi), first
 
@@ -348,70 +367,63 @@ def decode_sketch(
     replicates can never return a higher cost (all registry decoders report
     the same objective (4)).
 
-    Convergence tracing: when ``cfg.trace_convergence`` is set — or telemetry
-    is enabled (``repro.obs``) and this is a host-side call (``z`` not a
-    tracer) — the decoder runs with its ``trace`` flag on and the selected
-    replicate's trajectory is emitted as ``decoder.<name>.<series>`` events
-    on the default tracer.  The return contract stays ``(centroids, weights,
-    cost)`` either way.
+    Convergence tracing: when ``cfg.trace_convergence`` is set the decoder
+    runs with its ``trace`` flag on, and (under telemetry, ``repro.obs``) the
+    selected replicate's trajectory is emitted as ``decoder.<name>.<series>``
+    events on the default tracer.  The return contract stays ``(centroids,
+    weights, cost)`` either way.  The decode runs under the ``ckm.decode``
+    span.
     """
-    from repro.obs import runtime as obs_rt
-
-    w = fo.as_operator(w)
-    trace_on = cfg.trace_convergence
-    if not trace_on and obs_rt.ENABLED and not isinstance(z, jax.core.Tracer):
-        trace_on = True
-    run_cfg = (
-        cfg
-        if trace_on == cfg.trace_convergence
-        else dataclasses.replace(cfg, trace_convergence=trace_on)
-    )
-    decode = dec_mod.get_decoder(run_cfg.decoder)
-    keys = jnp.stack(
-        [jax.random.fold_in(key, r) for r in range(run_cfg.replicates)]
-    )
-    # Every decoder contraction (atoms, residuals, NNLS Gram) in f32: on the
-    # TPU a default-precision f32 matmul is a single bf16 pass.
-    with jax.default_matmul_precision("highest"):
-        if run_cfg.replicates == 1:
-            out = decode(keys[0], z, w, lower, upper, run_cfg, x_init)
-        elif x_init is None:
-            out = jax.lax.map(
-                lambda k_: decode(k_, z, w, lower, upper, run_cfg), keys
-            )
-        else:
-            out = jax.lax.map(
-                lambda k_: decode(k_, z, w, lower, upper, run_cfg, x_init),
-                keys,
-            )
-    # A tracing decoder returns (cents, alphas, cost, {series}); one with no
-    # trace support (or trace off) returns the plain 3-tuple.
-    traces = out[3] if len(out) == 4 else None
-    cents, alphas, costs = out[0], out[1], out[2]
-    if run_cfg.replicates > 1:
-        best = jnp.argmin(costs)
-        cents, alphas, costs = cents[best], alphas[best], costs[best]
-        if traces is not None:
-            traces = {name: vals[best] for name, vals in traces.items()}
-    if traces is not None and not isinstance(costs, jax.core.Tracer):
-        from repro.obs import trace as obs_trace
-
-        for name, vals in traces.items():
-            obs_trace.series(
-                f"decoder.{run_cfg.decoder}.{name}",
-                jnp.asarray(vals),
-                decoder=run_cfg.decoder,
-            )
-    return cents, alphas, costs
+    with obs_trace.span(
+        "ckm.decode", decoder=cfg.decoder, replicates=cfg.replicates
+    ):
+        w = fo.as_operator(w)
+        decode = dec_mod.get_decoder(cfg.decoder)
+        keys = jnp.stack(
+            [jax.random.fold_in(key, r) for r in range(cfg.replicates)]
+        )
+        # Every decoder contraction (atoms, residuals, NNLS Gram) in f32: on
+        # the TPU a default-precision f32 matmul is a single bf16 pass.
+        with jax.default_matmul_precision("highest"):
+            if cfg.replicates == 1:
+                out = decode(keys[0], z, w, lower, upper, cfg, x_init)
+            elif x_init is None:
+                out = jax.lax.map(
+                    lambda k_: decode(k_, z, w, lower, upper, cfg), keys
+                )
+            else:
+                out = jax.lax.map(
+                    lambda k_: decode(k_, z, w, lower, upper, cfg, x_init),
+                    keys,
+                )
+        # A tracing decoder returns (cents, alphas, cost, {series}); one with
+        # no trace support (or trace off) returns the plain 3-tuple.
+        traces = out[3] if len(out) == 4 else None
+        cents, alphas, costs = out[0], out[1], out[2]
+        if cfg.replicates > 1:
+            best = jnp.argmin(costs)
+            cents, alphas, costs = cents[best], alphas[best], costs[best]
+            if traces is not None:
+                traces = {name: vals[best] for name, vals in traces.items()}
+        if traces is not None and not isinstance(costs, jax.core.Tracer):
+            for name, vals in traces.items():
+                obs_trace.series(
+                    f"decoder.{cfg.decoder}.{name}",
+                    jnp.asarray(vals),
+                    decoder=cfg.decoder,
+                )
+        return cents, alphas, costs
 
 
 def fit(key: jax.Array, x: jax.Array, cfg: CKMConfig, mesh=None) -> CKMResult:
-    """End-to-end compressive K-means on an in-memory dataset."""
-    k_sketch, k_dec = jax.random.split(key)
-    z, op, sigma2, (lo, hi) = compute_sketch(k_sketch, x, cfg, mesh)
-    x_init = x if cfg.init in ("sample", "kpp") else None
-    cents, alphas, cost = decode_sketch(k_dec, z, op, lo, hi, cfg, x_init)
-    return CKMResult(cents, alphas, cost, sigma2, op, z, (lo, hi))
+    """End-to-end compressive K-means on an in-memory dataset (under the
+    root span ``ckm.fit``)."""
+    with obs_trace.span("ckm.fit", streaming=False):
+        k_sketch, k_dec = jax.random.split(key)
+        z, op, sigma2, (lo, hi) = compute_sketch(k_sketch, x, cfg, mesh)
+        x_init = x if cfg.init in ("sample", "kpp") else None
+        cents, alphas, cost = decode_sketch(k_dec, z, op, lo, hi, cfg, x_init)
+        return CKMResult(cents, alphas, cost, sigma2, op, z, (lo, hi))
 
 
 def fit_streaming(
@@ -423,15 +435,17 @@ def fit_streaming(
     be discarded immediately — the dataset never has to fit in memory, which
     is the paper's whole point (cost after sketching is N-independent).  The
     "sample"/"kpp" decoder inits draw from the *first* batch only (the rest
-    of the stream is gone by decode time).
+    of the stream is gone by decode time).  The whole fit runs under the
+    root span ``ckm.fit``; finalize is its own time.
     """
-    k_sketch, k_dec = jax.random.split(key)
-    z, op, sigma2, (lo, hi), first = compute_sketch_streaming(
-        k_sketch, batches, cfg, mesh
-    )
-    x_init = first if cfg.init in ("sample", "kpp") else None
-    cents, alphas, cost = decode_sketch(k_dec, z, op, lo, hi, cfg, x_init)
-    return CKMResult(cents, alphas, cost, sigma2, op, z, (lo, hi))
+    with obs_trace.span("ckm.fit", streaming=True):
+        k_sketch, k_dec = jax.random.split(key)
+        z, op, sigma2, (lo, hi), first = compute_sketch_streaming(
+            k_sketch, batches, cfg, mesh
+        )
+        x_init = first if cfg.init in ("sample", "kpp") else None
+        cents, alphas, cost = decode_sketch(k_dec, z, op, lo, hi, cfg, x_init)
+        return CKMResult(cents, alphas, cost, sigma2, op, z, (lo, hi))
 
 
 def diagnose(result: CKMResult, **kwargs):

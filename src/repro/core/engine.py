@@ -604,8 +604,7 @@ class SketchEngine:
             part = self._partial_state(batch, weights)
             if self.decay is not None:
                 part = self._lift_partial(part, self._resolve_t(state, t))
-            with obs_trace.span("engine.merge", backend=self.backend):
-                out = _merge_states(state, part)
+            out = _merge_states(state, part)
         h.update_calls.inc()
         h.update_rows.inc(float(np.shape(batch)[0]))
         h.merge_calls.inc()

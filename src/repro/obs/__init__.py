@@ -2,17 +2,19 @@
 
 Three layers, documented (with runnable snippets) in ``docs/observability.md``:
 
-- :mod:`repro.obs.runtime` — the master switch.  Everything below is inert
-  until :func:`enable` flips the module-level ``runtime.ENABLED`` bool; the
-  disabled hot path costs one attribute read + branch (pinned <= 2% on the
-  engine-update microbenchmark by the ``obs_overhead`` kernels row).
+- :mod:`repro.obs.runtime` — the master switch.  The tracer and the
+  registry are inert until :func:`enable` flips the module-level
+  ``runtime.ENABLED`` bool; the disabled hot path costs one attribute read +
+  branch.  Spans reach the profiler either way; off, they and the compile
+  listener cost ~0.6 ms of a 1.12 s paper-size fit on a TPU v5e host.
 - :mod:`repro.obs.metrics` / :mod:`repro.obs.trace` — a get-or-create
-  instrument registry (counters / gauges / histograms) and a span tracer
-  with JSONL export + ``jax.profiler.TraceAnnotation`` pass-through.  The
-  instrumented call sites live in ``core/engine.py`` (update/merge/finalize),
-  ``core/ingest.py`` (overlap accounting), ``serve/fleet_service.py``
-  (flush latency, decode-cache traffic) and the decoders (convergence
-  series).
+  instrument registry (counters / gauges / histograms) and spans that
+  always enter a ``jax.profiler.TraceAnnotation``, with JAX's compile time
+  charged to the innermost open span, and a tracer with JSONL export.  The
+  instrumented call sites live in ``core/ckm.py`` (the fit's spans),
+  ``core/engine.py`` (update/merge/finalize), ``core/ingest.py`` (overlap
+  accounting), ``serve/fleet_service.py`` (flush latency, decode-cache
+  traffic) and the decoders (convergence series).
 - :mod:`repro.obs.diagnose` — ``ckm.diagnose(result)``: attribute a bad fit
   to sketch size m, frequency scale sigma, or the decoder; plus the O(m)
   :func:`sketch_drift` score emitted as a gauge by ``FleetService.drift``

@@ -4,6 +4,8 @@ Covers the observability PR's acceptance criteria:
 
 - metrics registry / tracer semantics, and the disabled-path no-op contract
   (nothing recorded, results bitwise identical to an untelemetered run);
+- the fit's spans reach the profiler with telemetry off (one ``req`` per
+  fit), and JAX's compile time lands on the span that compiled, once;
 - instrumentation: engine update/merge/finalize spans + counters, ingest
   overlap accounting, FleetService flush/decode-cache/drift instruments;
 - an enabled ``fit_streaming`` run emits update/merge/finalize spans and a
@@ -152,6 +154,125 @@ def test_span_nesting_depth_and_jsonl(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# Spans on the profiler clock, and compile time per span
+# ---------------------------------------------------------------------------
+
+
+def _host_events(log_dir) -> dict:
+    """``{name: [(start_ns, end_ns, stats)]}`` of a profile's host events."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True))[-1]
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def _profiled(log_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
+
+
+def test_fit_spans_reach_the_profiler_with_telemetry_off(tmp_path, blobs3):
+    cfg = ckm_mod.CKMConfig(k=3, m=60, **FAST)
+    batches = [blobs3[i * 1000:(i + 1) * 1000] for i in range(3)]
+    with _profiled(tmp_path):
+        ckm_mod.fit_streaming(jax.random.PRNGKey(4), iter(batches), cfg)
+    assert obs.TRACER.events == [] and obs.snapshot() == {}
+    ev = _host_events(tmp_path)
+    (fit,) = ev["ckm.fit"]
+    assert len(ev["ckm.sigma2"]) == len(ev["ckm.decode"]) == 1
+    assert len(ev["ckm.operator"]) == 2  # the draw, then quantizer + engine
+    assert sorted(e[2]["chunk"] for e in ev["ckm.ingest"]) == [0, 1, 2]
+    children = [e for name in ("ckm.sigma2", "ckm.operator", "ckm.ingest",
+                               "ckm.decode") for e in ev[name]]
+    assert all(fit[0] <= a and b <= fit[1] for a, b, _ in children)
+    assert all(st["req"] == fit[2]["req"] and st["parent"] == "ckm.fit"
+               for _, _, st in children)
+    assert "parent" not in fit[2]
+    decode = ev["ckm.decode"][0][2]
+    assert decode["decoder"] == "clompr" and decode["replicates"] == 1
+    for _, _, st in [fit, *children]:
+        assert {"trace_ms", "lower_ms", "compile_ms", "jax_compiles"} <= set(st)
+
+
+def test_compile_time_lands_on_the_span_that_compiled(tmp_path):
+    import jax.monitoring as mon
+
+    traces = []
+
+    def listen(event, t0, t1, **kw):
+        if event.endswith("jaxpr_trace_duration"):
+            traces.append((kw.get("fun_name"), t0, t1))
+
+    @jax.jit
+    def inner(v):
+        return jnp.cos(v) + 1.0
+
+    @jax.jit
+    def outer_fn(v):
+        return inner(v) * 2.0
+
+    x = jnp.arange(7.0)
+    obs.enable()
+    mon.register_event_time_span_listener(listen)
+    try:
+        with _profiled(tmp_path):
+            with obs.span("outer"):
+                with obs.span("first"):
+                    outer_fn(x).block_until_ready()
+                with obs.span("again"):
+                    outer_fn(x).block_until_ready()
+    finally:
+        mon.unregister_event_time_span_listener(listen)
+    obs.disable()
+    st = {name: evs[0][2] for name, evs in _host_events(tmp_path).items()
+          if name in ("outer", "first", "again")}
+    assert st["first"]["trace_ms"] > 0 and st["first"]["compile_ms"] > 0
+    assert st["first"]["jax_compiles"] >= 1
+    for other in ("outer", "again"):
+        assert st[other]["trace_ms"] == st[other]["compile_ms"] == 0
+        assert st[other]["jax_compiles"] == 0
+    # The inner jit is traced inside the outer one's trace: the span holds
+    # the union of the two, the outer trace's length, not their sum.
+    (_, a, b), = [t for t in traces if t[0] == "outer_fn"]
+    assert [t for t in traces if t[0] == "inner" and a <= t[1] and t[2] <= b]
+    assert st["first"]["trace_ms"] == pytest.approx(1e3 * (b - a), rel=1e-6)
+    snap = obs.snapshot()
+    assert snap["jax.compile.seconds{span=first,stage=trace}"] \
+        == pytest.approx(b - a, rel=1e-6)
+    assert snap["jax.compile.events{span=first,stage=compile}"] \
+        == st["first"]["jax_compiles"]
+    assert not any("span=again" in k or "span=outer" in k for k in snap)
+    stages = [e for e in obs.TRACER.spans() if e["name"].startswith("jax.")]
+    assert {e["parent"] for e in stages} == {"first"}
+    assert {e["name"] for e in stages} == {"jax.trace", "jax.lower",
+                                           "jax.compile"}
+
+
+def test_fit_bitwise_equal_with_telemetry_on_and_off(blobs3):
+    cfg = ckm_mod.CKMConfig(k=3, m=60, **FAST)
+    batches = [blobs3[i * 1000:(i + 1) * 1000] for i in range(3)]
+    off = ckm_mod.fit_streaming(jax.random.PRNGKey(9), iter(batches), cfg)
+    assert obs.TRACER.events == [] and obs.snapshot() == {}
+    obs.enable()
+    on = ckm_mod.fit_streaming(jax.random.PRNGKey(9), iter(batches), cfg)
+    obs.disable()
+    assert obs.TRACER.spans("ckm.decode")
+    assert jnp.array_equal(off.centroids, on.centroids)
+    assert jnp.array_equal(off.weights, on.weights)
+    assert jnp.array_equal(off.sketch, on.sketch)
+
+
+# ---------------------------------------------------------------------------
 # Engine instrumentation
 # ---------------------------------------------------------------------------
 
@@ -173,6 +294,7 @@ def test_engine_spans_and_counters(rng):
     obs.enable()
     state = eng.update(eng.init_state(), x)
     state = eng.update(state, x[:20])
+    state = eng.merge(state, eng.init_state())
     eng.finalize(state)
     obs.disable()
     snap = obs.snapshot()
@@ -182,7 +304,7 @@ def test_engine_spans_and_counters(rng):
     assert snap["engine.state.bytes{backend=xla,bits=none}"] > 0
     names = [e["name"] for e in obs.TRACER.spans()]
     assert names.count("engine.update") == 2
-    assert names.count("engine.merge") == 2
+    assert names.count("engine.merge") == 1
     assert names.count("engine.finalize") == 1
 
 
@@ -286,7 +408,7 @@ def test_sketch_shift_trace_output(blobs3):
 
 def test_decode_sketch_emits_series_when_enabled(blobs3):
     z, op, lo, hi = _sketch_for_decode(blobs3)
-    cfg = ckm_mod.CKMConfig(k=3, m=60, **FAST)
+    cfg = ckm_mod.CKMConfig(k=3, m=60, trace_convergence=True, **FAST)
     c0, a0, cost0 = ckm_mod.decode_sketch(
         jax.random.PRNGKey(2), z, op, lo, hi, cfg
     )
@@ -304,7 +426,7 @@ def test_decode_sketch_emits_series_when_enabled(blobs3):
 def test_decode_sketch_traces_best_replicate(blobs3):
     z, op, lo, hi = _sketch_for_decode(blobs3)
     cfg = ckm_mod.CKMConfig(k=3, m=60, replicates=2, decoder="sketch_shift",
-                            **FAST)
+                            trace_convergence=True, **FAST)
     obs.enable()
     _, _, cost = ckm_mod.decode_sketch(
         jax.random.PRNGKey(2), z, op, lo, hi, cfg
@@ -324,15 +446,22 @@ def test_decode_sketch_traces_best_replicate(blobs3):
 
 
 def test_fit_streaming_jsonl_acceptance(tmp_path, blobs3):
-    cfg = ckm_mod.CKMConfig(k=3, m=60, **FAST)
+    cfg = ckm_mod.CKMConfig(k=3, m=60, trace_convergence=True, **FAST)
     batches = [blobs3[i * 500:(i + 1) * 500] for i in range(6)]
     obs.enable()
     res = ckm_mod.fit_streaming(jax.random.PRNGKey(1), iter(batches), cfg)
     path = obs.export_jsonl(tmp_path / "run.jsonl")
     obs.disable()
     lines = [json.loads(l) for l in path.read_text().splitlines()]
-    span_names = {e["name"] for e in lines if e["kind"] == "span"}
-    assert {"engine.update", "engine.merge", "engine.finalize"} <= span_names
+    spans = [e for e in lines if e["kind"] == "span"]
+    span_names = {e["name"] for e in spans}
+    assert {"engine.update", "engine.finalize", "ckm.fit", "ckm.sigma2",
+            "ckm.operator", "ckm.ingest", "ckm.decode"} <= span_names
+    root = next(e for e in spans if e["name"] == "ckm.fit")
+    assert root["parent"] is None and root["depth"] == 0
+    assert all(e["req"] == root["req"] for e in spans)
+    assert {e["parent"] for e in spans if e["name"] == "engine.update"} \
+        == {"ckm.ingest"}
     series = [e for e in lines if e["kind"] == "series"]
     assert any(e["name"] == "decoder.clompr.residual_norm" for e in series)
     vals = next(e for e in series
