@@ -142,16 +142,18 @@ def fleet_quantizers(
 def stack_operators(ops: Sequence[fo.FrequencyOperator]):
     """Stack operator leaves along a new leading tenant axis.
 
-    Returns ``(stacked_op, treedefs)``: ``stacked_op`` is a pytree of the
+    Returns ``(stacked_op, treedef)``: ``stacked_op`` is a pytree of the
     operator class whose array leaves carry the tenant axis (valid *only* as
-    a vmap/gather carrier — its static n/m/spec aux comes from tenant 0), and
-    ``treedefs`` the per-tenant treedefs used to slice true per-tenant
-    operators back out.
+    a vmap/gather carrier), and ``treedef`` the tenants' shared treedef,
+    used to slice per-tenant operators back out.  A treedef holds only what
+    shapes the traced program (the family, and ``(n, m)`` for structured
+    operators), never the spec, so every tenant of a valid fleet has the
+    same one.
     """
     flat = [jax.tree_util.tree_flatten(op) for op in ops]
     leaves0, treedef0 = flat[0]
-    for t, (leaves, _) in enumerate(flat[1:], start=1):
-        if len(leaves) != len(leaves0) or any(
+    for t, (leaves, treedef) in enumerate(flat[1:], start=1):
+        if treedef != treedef0 or any(
             a.shape != b.shape or a.dtype != b.dtype
             for a, b in zip(leaves, leaves0)
         ):
@@ -160,10 +162,7 @@ def stack_operators(ops: Sequence[fo.FrequencyOperator]):
                 "(all fleet tenants must share the operator family and (n, m))"
             )
     stacked = [jnp.stack(ls) for ls in zip(*(leaves for leaves, _ in flat))]
-    return (
-        jax.tree_util.tree_unflatten(treedef0, stacked),
-        [treedef for _, treedef in flat],
-    )
+    return jax.tree_util.tree_unflatten(treedef0, stacked), treedef0
 
 
 class FleetEngine:
@@ -252,7 +251,7 @@ class FleetEngine:
         self.specs: tuple[fo.FreqOpSpec | None, ...] = tuple(
             self._try_spec(op) for op in ops
         )
-        self._stacked_op, self._op_treedefs = stack_operators(ops)
+        self._stacked_op, self._op_treedef = stack_operators(ops)
         self._op_leaves = jax.tree_util.tree_leaves(self._stacked_op)
         self.bits: int | None = None
         self.dither: jax.Array | None = None
@@ -367,10 +366,12 @@ class FleetEngine:
     # -- per-tenant views ---------------------------------------------------
 
     def operator(self, tenant: int) -> fo.FrequencyOperator:
-        """Tenant ``tenant``'s own operator, sliced from the stacked leaves
-        (bitwise the operator it was constructed from)."""
+        """Tenant ``tenant``'s own operator, sliced from the stacked leaves:
+        its leaves are bitwise those of the operator it was constructed from,
+        but, rebuilt from leaves, it carries no spec (``spec()`` raises);
+        the tenant's recipe is ``specs[tenant]``."""
         leaves = [l[tenant] for l in self._op_leaves]
-        return jax.tree_util.tree_unflatten(self._op_treedefs[tenant], leaves)
+        return jax.tree_util.tree_unflatten(self._op_treedef, leaves)
 
     def quantizer(self, tenant: int) -> qz.SketchQuantizer | None:
         if self.bits is None:

@@ -23,6 +23,14 @@ determines the operator, so engine state, checkpoints and cross-host
 broadcast can carry ~O(1) bytes (``spec_wire_bytes``) and rebuild the
 operator with :func:`from_spec` instead of shipping the O(n·m) matrix.
 
+The spec is host bookkeeping on the eager operator object, recorded when
+the operator is drawn; it is not pytree data.  A treedef holds only what shapes the traced
+program, so operators that differ only in key or sigma^2 share every compiled
+program, and an operator rebuilt from its leaves (inside ``jit`` or
+``shard_map``, out of a ``tree_map``, a fleet tenant's slice) carries no spec:
+its ``spec()`` raises, and ``SketchEngine.spec()`` / ``FleetEngine.specs``
+hold the recipe.
+
 Raw arrays: :func:`as_operator` wraps a raw ``(n, m)`` array in a ``"dense"``
 operator (such a wrapper has no spec; ``spec()`` raises).  The sketch/engine
 entry points still wrap silently for convenience, but the decoder helpers and
@@ -129,7 +137,10 @@ class FrequencyOperator:
 
     Subclasses must be registered JAX pytrees (their array leaves flow through
     ``jit`` / ``scan`` / ``shard_map`` transparently; static hyperparameters
-    and the spec live in hashable aux data) and define ``name``, ``n``, ``m``.
+    that shape the program live in hashable aux data) and define ``name``,
+    ``n``, ``m``.  The spec stays out of the aux data: the treedef is part of
+    every ``jit`` cache key, so a spec there would retrace every jitted
+    function for each new key or sigma^2.
     """
 
     name: str = "?"
@@ -166,7 +177,8 @@ class FrequencyOperator:
 
     # -- bookkeeping -------------------------------------------------------
     def spec(self) -> FreqOpSpec:
-        """The O(1) rebuild recipe; raises for shim-wrapped raw matrices."""
+        """The O(1) rebuild recipe; raises ``ValueError`` for raw matrices
+        and for operators rebuilt from their leaves, which carry none."""
         raise NotImplementedError
 
     def state_bytes(self) -> int:

@@ -59,19 +59,24 @@ class DenseOperator(FrequencyOperator):
     def spec(self) -> FreqOpSpec:
         if self._spec is None:
             raise ValueError(
-                "this DenseOperator wraps a raw matrix (deprecation shim) and "
-                "has no spec; build it with freq_ops.make_operator('dense', "
-                "key, m, n, sigma2) to carry one"
+                "this DenseOperator has no spec: it wraps a raw matrix, or "
+                "was rebuilt from its leaves (inside jit/shard_map, by a "
+                "tree_map, or as a fleet tenant's slice), and the spec is "
+                "not pytree data; build it with freq_ops.make_operator("
+                "'dense', key, m, n, sigma2), or read the recipe from "
+                "SketchEngine.spec() / FleetEngine.specs"
             )
         return self._spec
 
 
+# No aux data: the leaf's shape is all that shapes the program, and the spec
+# stays out of every jit cache key (see ``FrequencyOperator``).
 def _flatten(op: DenseOperator):
-    return (op.w,), (op._spec,)
+    return (op.w,), ()
 
 
 def _unflatten(aux, children):
-    return DenseOperator(children[0], aux[0])
+    return DenseOperator(children[0])
 
 
 jax.tree_util.register_pytree_node(DenseOperator, _flatten, _unflatten)

@@ -123,18 +123,23 @@ class StructuredOperator(FrequencyOperator):
     def spec(self) -> FreqOpSpec:
         if self._spec is None:
             raise ValueError(
-                "this structured operator has no spec (built under "
-                "jit/vmap tracing, where no concrete key exists)"
+                "this structured operator has no spec: it was built under "
+                "jit/vmap tracing, where no concrete key exists, or rebuilt "
+                "from its leaves (inside jit/shard_map, by a tree_map, or as "
+                "a fleet tenant's slice), and the spec is not pytree data; "
+                "read the recipe from SketchEngine.spec() / FleetEngine.specs"
             )
         return self._spec
 
 
+# Only n and m shape the traced program; the spec stays out of the aux data,
+# and so out of every jit cache key (see ``FrequencyOperator``).
 def _flatten(op: StructuredOperator):
-    return (op.diags, op.radii, op.rho), (op._n, op._m, op._spec)
+    return (op.diags, op.radii, op.rho), (op._n, op._m)
 
 
 def _unflatten(aux, children):
-    return StructuredOperator(*children, n=aux[0], m=aux[1], spec=aux[2])
+    return StructuredOperator(*children, n=aux[0], m=aux[1])
 
 
 jax.tree_util.register_pytree_node(StructuredOperator, _flatten, _unflatten)

@@ -46,6 +46,11 @@ def radius_from_uniform(u: jax.Array, sigma2: jax.Array, dtype=jnp.float32) -> j
     on identical uniforms (``dtype`` controls the grid/CDF precision).
     """
     u = jnp.asarray(u, dtype)
+    # A uniform of exactly 0 (a float32 draw's 2^-23 step, ~1e-4 per
+    # 1000-frequency draw) would map to the radius 0: a frequency with no
+    # direction, whose sketch entry is 1 for every point.  The law has no
+    # mass there, so it takes the middle of its bin instead.
+    u = jnp.where(u > 0, u, jnp.asarray(2.0**-24, dtype))
     sigma2 = jnp.asarray(sigma2, dtype)
     sigma = jnp.sqrt(sigma2)
     grid = jnp.linspace(

@@ -213,3 +213,46 @@ class TestPRNGStreams:
             k_dither, (16,), minval=0.0, maxval=2.0 * np.pi
         )
         np.testing.assert_array_equal(np.asarray(q.dither), np.asarray(expect))
+
+
+class TestCompileReuse:
+    def test_fit_under_a_new_key_reuses_compiled_programs(self):
+        """Regression: the operator's spec (PRNG words, sigma^2) was pytree
+        aux data, so every fit under a new key lowered and compiled the
+        decoder and the first chunk's update again.  A warm fit under another
+        key adds no lowering and no backend compile, and gives bitwise what
+        the same key gives from a fresh cache."""
+        import jax.monitoring as mon
+
+        cfg = ckm_mod.CKMConfig(
+            k=3, m=60, atom_steps=40, joint_steps=30, nnls_iters=40,
+            final_steps=80,
+        )
+        x = synthetic.gaussian_mixture(jax.random.PRNGKey(3), 3000, k=3, n=2,
+                                       c=6.0)
+
+        def batches():
+            return iter([x[i * 1000:(i + 1) * 1000] for i in range(3)])
+
+        ckm_mod.fit_streaming(jax.random.PRNGKey(1), batches(), cfg)
+
+        events = []
+
+        def listen(event, t0, t1, **kw):
+            if event.endswith(("jaxpr_to_mlir_module_duration",
+                               "backend_compile_duration")):
+                events.append(event)
+
+        mon.register_event_time_span_listener(listen)
+        try:
+            warm = ckm_mod.fit_streaming(jax.random.PRNGKey(2), batches(), cfg)
+            jax.block_until_ready(warm.centroids)
+        finally:
+            mon.unregister_event_time_span_listener(listen)
+        assert events == []
+        jax.clear_caches()
+        fresh = ckm_mod.fit_streaming(jax.random.PRNGKey(2), batches(), cfg)
+        np.testing.assert_array_equal(np.asarray(warm.centroids),
+                                      np.asarray(fresh.centroids))
+        np.testing.assert_array_equal(np.asarray(warm.cost),
+                                      np.asarray(fresh.cost))

@@ -287,6 +287,29 @@ class TestSpec:
         ops = _ops(n=n, m=m)
         assert ops["structured"].state_bytes() < 0.1 * ops["dense"].state_bytes()
 
+    @pytest.mark.parametrize("name", ["dense", "structured"])
+    def test_treedef_ignores_key_and_sigma2(self, name):
+        """The spec is host bookkeeping, not pytree aux data: operators that
+        differ in key and sigma^2 share one treedef (so one jit cache entry),
+        the eager operators keep their own recipes, and an operator rebuilt
+        from its leaves carries none."""
+        a = fo.make_operator(name, jax.random.PRNGKey(1), 80, 6, 0.7)
+        b = fo.make_operator(name, jax.random.PRNGKey(2), 80, 6, 1.9)
+        assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
+        for op, seed, sigma2 in ((a, 1, 0.7), (b, 2, 1.9)):
+            spec = op.spec()
+            assert spec.key_data == fo.base.key_data_tuple(
+                jax.random.PRNGKey(seed))
+            assert spec.sigma2 == pytest.approx(sigma2)
+            rebuilt = fo.from_spec(spec)
+            for x, y in zip(jax.tree.leaves(op), jax.tree.leaves(rebuilt)):
+                assert bool(jnp.array_equal(x, y))
+        out = jax.jit(lambda o: o)(a)
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(out)):
+            assert bool(jnp.array_equal(x, y))
+        with pytest.raises(ValueError, match="no spec"):
+            out.spec()
+
     def test_raw_matrix_has_no_spec(self):
         w = jnp.ones((3, 8))
         with pytest.raises(ValueError, match="no spec"):
@@ -381,6 +404,17 @@ class TestDtypeSatellite:
                     fq.radius_from_uniform(u, sigma2, jnp.float64)
                 )
             np.testing.assert_allclose(r32, r64, rtol=2e-4, atol=1e-6)
+
+    def test_zero_uniform_draws_no_zero_frequency(self):
+        """Regression: a radius uniform of exactly 0 (PRNGKey(15642)'s dense
+        draw of 1000 has one) mapped to a zero frequency, which has no
+        direction and sketches every point to 1.  It now takes the middle
+        of its 2^-23 bin, below every other draw's radius (the structured
+        operator's radii come from the same sampler)."""
+        r = np.asarray(fq.radius_from_uniform(np.array([0.0, 2.0**-23]), 1.0))
+        assert 0 < r[0] < r[1]
+        w = fq.draw_frequencies(jax.random.PRNGKey(15642), 1000, 10, 1.0)
+        assert float(jnp.min(jnp.linalg.norm(w, axis=0))) > 0
 
     def test_ckm_config_propagates_dtype(self):
         x = jax.random.normal(jax.random.PRNGKey(0), (256, 3))
