@@ -685,26 +685,32 @@ class SketchEngine:
 
     def _finalize_impl(self, state):
         if self.quantizer is not None:
-            # int32 code sums wrap silently once count * scale exceeds the
-            # int32 range — detect post-hoc from the (non-wrapping) f32 count
-            # rather than garbage-decode.  Skipped under tracing.
-            cap = qz.accumulator_capacity(self.quantizer.bits)
-            if not isinstance(state.count, jax.core.Tracer) and float(
-                state.count
-            ) > cap:
-                raise ValueError(
-                    f"quantized accumulators overflow: {float(state.count):.0f} "
-                    f"points folded at {self.quantizer.bits} bits exceeds the "
-                    f"int32 capacity of {cap} points "
-                    "(core.quantize.accumulator_capacity)"
-                )
-            if isinstance(state, DecayedQuantizedSketchEngineState):
-                return _finalize_decayed_quantized(
+            from repro.obs import trace as obs_trace
+
+            # The capacity check and the dequantization, under one span.
+            with obs_trace.span("engine.dequantize", bits=self.quantizer.bits):
+                # int32 code sums wrap silently once count * scale exceeds
+                # the int32 range — detect post-hoc from the (non-wrapping)
+                # f32 count rather than garbage-decode.  Skipped under
+                # tracing.  ``float`` waits for the device: one sync.
+                cap = qz.accumulator_capacity(self.quantizer.bits)
+                if not isinstance(state.count, jax.core.Tracer) and float(
+                    state.count
+                ) > cap:
+                    raise ValueError(
+                        f"quantized accumulators overflow: "
+                        f"{float(state.count):.0f} points folded at "
+                        f"{self.quantizer.bits} bits exceeds the int32 "
+                        f"capacity of {cap} points "
+                        "(core.quantize.accumulator_capacity)"
+                    )
+                if isinstance(state, DecayedQuantizedSketchEngineState):
+                    return _finalize_decayed_quantized(
+                        state, self.quantizer.dither, self.quantizer.bits
+                    )
+                return _finalize_quantized(
                     state, self.quantizer.dither, self.quantizer.bits
                 )
-            return _finalize_quantized(
-                state, self.quantizer.dither, self.quantizer.bits
-            )
         # ``_finalize_state`` duck-types over the float flavours — the decayed
         # state has the same accumulator fields (jit retraces per pytree).
         return _finalize_state(state)
