@@ -24,6 +24,9 @@ Encoding (per point ``x``, frequency ``w_j``, dither ``xi_j ~ U[0, 2pi)``)::
     b-bit:   q_c = round(S * cos theta_j),       q_s = round(S * sin theta_j)
              with S = 2**(b-1) - 1 levels per sign
 
+The 1-bit code computes no cos or sin: both signs are read off the quadrant
+of ``theta`` (:func:`one_bit_codes`), in XLA and in the Pallas kernels alike.
+
 Decoding (the known E[sign] correction).  The square wave has the Fourier
 series ``sign(cos t) = (4/pi) sum_k (-1)^k cos((2k+1) t) / (2k+1)``, so the
 mean of signs over the data is, per frequency,
@@ -60,6 +63,7 @@ __all__ = [
     "make_quantizer",
     "quantization_scale",
     "accumulator_capacity",
+    "one_bit_codes",
     "quantize_codes",
     "dequantize_sums",
     "state_wire_bytes",
@@ -138,21 +142,55 @@ def make_quantizer(key: jax.Array, m: int, spec: str) -> SketchQuantizer | None:
     return SketchQuantizer(bits=bits, dither=draw_dither(key, m))
 
 
+# pi/2 split into three float32 constants (Cody-Waite).  The first two have
+# at most 10 significant bits, so ``k * C`` is exact in float32 for
+# |k| < 2**14; what the three leave of pi/2 is 1.7e-15.
+_HALF_PI_1 = 1.5703125
+_HALF_PI_2 = 4.837512969970703125e-4
+_HALF_PI_3 = 7.549790126404332e-08
+_TWO_OVER_PI = 2.0 / math.pi
+
+
+def one_bit_codes(theta: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """int32 ``(sign(cos theta), sign(sin theta))`` of float32 phases, 0 -> +1.
+
+    Both signs depend only on the quadrant of ``theta``, so no cos or sin
+    is computed.  ``k0 = floor(theta * 2/pi + 1/2)`` is the nearest multiple
+    of pi/2 up to rounding; ``r = theta - k0 * pi/2``, reduced against the
+    three-part pi/2, then lies well inside (-pi/2, pi/2) and its sign is
+    exact, so the quadrant is ``k = k0 - (r < 0)``.  ``cos`` is >= 0 in
+    quadrants 0 and 3 (``(k + 1) & 2 == 0``), ``sin`` in quadrants 0 and 1
+    (``k & 2 == 0``); two's complement ``&`` gives negative ``k`` the same
+    period.  Only float32 mul/sub/floor, compares, selects, a float->int32
+    convert and int32 ``&``/add: ops that Mosaic and XLA round alike, so the
+    Pallas kernels and the XLA path make the same codes bit for bit.
+    """
+    k0 = jnp.floor(theta * _TWO_OVER_PI + 0.5)
+    r = ((theta - k0 * _HALF_PI_1) - k0 * _HALF_PI_2) - k0 * _HALF_PI_3
+    k = k0.astype(jnp.int32)
+    k = jnp.where(r < 0, k - 1, k)
+    qc = jnp.where(((k + 1) & 2) == 0, 1, -1).astype(jnp.int32)
+    qs = jnp.where((k & 2) == 0, 1, -1).astype(jnp.int32)
+    return qc, qs
+
+
 def quantize_codes(
     proj: jax.Array, dither: jax.Array, bits: int, valid: jax.Array | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """Integer codes of one projection block.
 
     ``proj``: (..., m) raw phases ``x @ W``; ``dither``: (m,).  Returns int32
-    ``(q_cos, q_sin)`` of the same shape.  ``valid`` (broadcastable 0/1 mask)
-    zeroes padding rows so they cannot shift the integer sums.
+    ``(q_cos, q_sin)`` of the same shape.  The 1-bit code comes from the
+    phase's quadrant (:func:`one_bit_codes`, no cos or sin), as in the
+    Pallas kernels; the b-bit code rounds ``S * cos`` and ``S * sin``.
+    ``valid`` (broadcastable 0/1 mask) zeroes padding rows so they cannot
+    shift the integer sums.
     """
     theta = proj + dither
-    c, s = jnp.cos(theta), jnp.sin(theta)
     if bits == 1:
-        qc = jnp.where(c >= 0, 1, -1)
-        qs = jnp.where(s >= 0, 1, -1)
+        qc, qs = one_bit_codes(theta)
     else:
+        c, s = jnp.cos(theta), jnp.sin(theta)
         scale = float(quantization_scale(bits))
         qc = jnp.round(c * scale).astype(jnp.int32)
         qs = jnp.round(s * scale).astype(jnp.int32)

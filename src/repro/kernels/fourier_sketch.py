@@ -20,10 +20,13 @@ N to the block size, and the feature dim n to a multiple of 8; f32 tiles are
 
 ``quantized_fourier_sketch_kernel`` is the QCKM (core/quantize.py) variant of
 the same tiling: it adds the per-frequency dither to the projection tile,
-quantizes cos/sin to integer codes on the VPU, and accumulates **int32** sums
+turns the phases into integer codes on the VPU, and accumulates **int32** sums
 — signs never leave VMEM unaccumulated, so the quantized encoder costs the
 same HBM traffic as the float one while its partial state shrinks to integer
-accumulators.
+accumulators.  Its 1-bit code needs no cos or sin: ``sign(cos)`` and
+``sign(sin)`` are read from the quadrant of the phase, found by a
+three-constant reduction against pi/2 (``core.quantize.one_bit_codes``, the
+helper every backend shares, so their code sums agree bitwise).
 """
 
 from __future__ import annotations
@@ -33,6 +36,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.core import quantize as qz
 
 
 def split_matmul(a: jax.Array, b: jax.Array, *, in_kernel: bool = False):
@@ -99,12 +104,14 @@ def _sketch_kernel(x_ref, w_ref, b_ref, cos_ref, sin_ref):
     sin_ref[...] += jnp.sum(jnp.sin(proj) * beta, axis=0, keepdims=True)
 
 
-def _quantized_sketch_kernel(x_ref, w_ref, d_ref, v_ref, qcos_ref, qsin_ref, *, scale):
+def _quantized_sketch_kernel(x_ref, w_ref, d_ref, v_ref, qcos_ref, qsin_ref, *, bits):
     """One (bN, bM) tile of the QCKM encoder: dithered phases -> int32 codes.
 
-    ``scale`` is static: 1 -> the 1-bit sign code; S > 1 -> round(S * cos/sin).
-    The whole tile stays in VMEM: MXU projection, VPU trig + rounding, and an
-    integer batch-reduction straight into the int32 output block.
+    The codes are ``core.quantize.quantize_codes``' (``bits`` is static): at
+    1 bit the phase's quadrant, with no cos or sin; at b bits
+    ``round(S * cos/sin)``.  The whole tile stays in VMEM: MXU projection,
+    VPU codes, and an integer batch-reduction straight into the int32 output
+    block.
     """
     j = pl.program_id(1)
 
@@ -114,28 +121,22 @@ def _quantized_sketch_kernel(x_ref, w_ref, d_ref, v_ref, qcos_ref, qsin_ref, *, 
         qsin_ref[...] = jnp.zeros_like(qsin_ref)
 
     # MXU: the phases bitwise as core.sketch.sketch_quantized computes them.
-    theta = split_matmul(x_ref[...], w_ref[...], in_kernel=True) + d_ref[...]
-    c, s = jnp.cos(theta), jnp.sin(theta)
-    if scale == 1:
-        qc = jnp.where(c >= 0, 1, -1)
-        qs = jnp.where(s >= 0, 1, -1)
-    else:
-        qc = jnp.round(c * float(scale)).astype(jnp.int32)
-        qs = jnp.round(s * float(scale)).astype(jnp.int32)
-    v = v_ref[...].astype(jnp.int32)  # (bN, 1) 0/1 — zero out padding rows
-    qcos_ref[...] += jnp.sum(qc.astype(jnp.int32) * v, axis=0, keepdims=True)
-    qsin_ref[...] += jnp.sum(qs.astype(jnp.int32) * v, axis=0, keepdims=True)
+    proj = split_matmul(x_ref[...], w_ref[...], in_kernel=True)
+    # (bN, 1) 0/1 valid mask zeroes the padding rows.
+    qc, qs = qz.quantize_codes(proj, d_ref[...], bits, valid=v_ref[...])
+    qcos_ref[...] += jnp.sum(qc, axis=0, keepdims=True)
+    qsin_ref[...] += jnp.sum(qs, axis=0, keepdims=True)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "block_n", "block_m", "interpret")
+    jax.jit, static_argnames=("bits", "block_n", "block_m", "interpret")
 )
 def quantized_fourier_sketch_kernel(
     x: jax.Array,
     w: jax.Array,
     dither: jax.Array,
     valid: jax.Array,
-    scale: int = 1,
+    bits: int = 1,
     block_n: int = 1024,
     block_m: int = 512,
     interpret: bool = False,
@@ -150,7 +151,7 @@ def quantized_fourier_sketch_kernel(
     assert n_pts % block_n == 0 and m % block_m == 0, (n_pts, m)
     grid = (m // block_m, n_pts // block_n)
     return pl.pallas_call(
-        functools.partial(_quantized_sketch_kernel, scale=scale),
+        functools.partial(_quantized_sketch_kernel, bits=bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, feat), lambda i, j: (j, 0)),

@@ -40,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core import quantize as qz
+
 
 @functools.lru_cache(maxsize=None)
 def _hadamard_np(k: int) -> np.ndarray:
@@ -144,9 +146,10 @@ def _structured_sketch_kernel(x_ref, dg_ref, r_ref, h_ref, b_ref, cos_ref, sin_r
 
 def _quantized_structured_sketch_kernel(
     x_ref, dg_ref, r_ref, dth_ref, h_ref, v_ref, qcos_ref, qsin_ref,
-    *, scale,
+    *, bits,
 ):
-    """QCKM twin: dithered WHT-chain phases -> int32 code sums in VMEM."""
+    """QCKM twin: dithered WHT-chain phases -> int32 code sums in VMEM, the
+    codes ``core.quantize.quantize_codes``' (1 bit: the quadrant, no trig)."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -156,17 +159,12 @@ def _quantized_structured_sketch_kernel(
 
     d = x_ref.shape[-1]
     v = _hd_chain_tile(x_ref[...], dg_ref[...], h_ref[...], d)
-    theta = v * r_ref[...] + dth_ref[...]
-    c, s = jnp.cos(theta), jnp.sin(theta)
-    if scale == 1:
-        qc = jnp.where(c >= 0, 1, -1)
-        qs = jnp.where(s >= 0, 1, -1)
-    else:
-        qc = jnp.round(c * float(scale)).astype(jnp.int32)
-        qs = jnp.round(s * float(scale)).astype(jnp.int32)
-    valid = v_ref[...].astype(jnp.int32)  # (bN, 1) 0/1 — zero padding rows
-    qcos_ref[...] += jnp.sum(qc.astype(jnp.int32) * valid, axis=0, keepdims=True)
-    qsin_ref[...] += jnp.sum(qs.astype(jnp.int32) * valid, axis=0, keepdims=True)
+    # (bN, 1) 0/1 valid mask zeroes the padding rows.
+    qc, qs = qz.quantize_codes(
+        v * r_ref[...], dth_ref[...], bits, valid=v_ref[...]
+    )
+    qcos_ref[...] += jnp.sum(qc, axis=0, keepdims=True)
+    qsin_ref[...] += jnp.sum(qs, axis=0, keepdims=True)
 
 
 # One frequency block's row of a per-block ``(nblocks, 1, d)`` array, seen by
@@ -225,14 +223,14 @@ def structured_sketch_kernel(
     return cos_s.reshape(nblocks, d), sin_s.reshape(nblocks, d)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bits", "block_n", "interpret"))
 def quantized_structured_sketch_kernel(
     x: jax.Array,  # (N, d)
     diags: jax.Array,  # (nblocks, 3, d)
     radii: jax.Array,  # (nblocks, d)
     dither: jax.Array,  # (nblocks, d)
     valid: jax.Array,  # (N, 1)
-    scale: int = 1,
+    bits: int = 1,
     block_n: int = 256,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
@@ -243,7 +241,7 @@ def quantized_structured_sketch_kernel(
     grid = (nblocks, n_pts // block_n)
     rows = lambda v: v.reshape(nblocks, 1, d)  # noqa: E731
     qcos, qsin = pl.pallas_call(
-        functools.partial(_quantized_structured_sketch_kernel, scale=scale),
+        functools.partial(_quantized_structured_sketch_kernel, bits=bits),
         grid=grid,
         in_specs=_specs(d, block_n, extra_freq_rows=1),
         out_specs=[_freq_row(d), _freq_row(d)],

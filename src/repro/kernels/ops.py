@@ -171,7 +171,6 @@ def quantized_fourier_sketch_sums(
     Padding rows carry ``valid=0`` so they contribute zero codes.
     """
     from repro.core import freq_ops
-    from repro.core import quantize as qz
     from repro.core import sketch as core_sk
     from repro.kernels import fourier_sketch as _qsk
 
@@ -200,7 +199,7 @@ def quantized_fourier_sketch_sums(
         qcos, qsin = _ft.quantized_structured_sketch_kernel(
             xp, jnp.asarray(op.diags, jnp.float32),
             jnp.asarray(op.radii, jnp.float32), dth, valid_p,
-            scale=qz.quantization_scale(bits), block_n=block_n,
+            bits=bits, block_n=block_n,
             interpret=interpret,
         )
         return qcos.reshape(-1)[:m], qsin.reshape(-1)[:m]
@@ -225,7 +224,7 @@ def quantized_fourier_sketch_sums(
         w,
         dither,
         valid,
-        scale=qz.quantization_scale(bits),
+        bits=bits,
         block_n=block_n,
         block_m=block_m,
         interpret=interpret,

@@ -19,10 +19,12 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core import ckm as ckm_mod
 from repro.core import engine as eng_mod
+from repro.core import freq_ops as fo
 from repro.core import frequencies as fq
 from repro.core import quantize as qz
 from repro.core import sketch as sk
 from repro.data import pipeline as pipe
+from repro.kernels import ops
 
 
 def _data(seed, npts=400, n=4, m=24):
@@ -82,6 +84,88 @@ class TestParseAndWire:
         bad = qz.SketchQuantizer(1, jnp.zeros((7,), jnp.float32))
         with pytest.raises(ValueError):
             eng_mod.SketchEngine(w, "xla", quantizer=bad)
+
+
+def _f64_signs(theta):
+    """float64 ``sign(cos)`` / ``sign(sin)`` of float32 phases, 0 -> +1."""
+    t = np.asarray(theta, np.float32).astype(np.float64)
+    return np.where(np.cos(t) >= 0, 1, -1), np.where(np.sin(t) >= 0, 1, -1)
+
+
+def _code_mismatches(theta):
+    qc, qs = jax.jit(qz.one_bit_codes)(jnp.asarray(theta, jnp.float32))
+    assert qc.dtype == qs.dtype == jnp.int32
+    rc, rs = _f64_signs(theta)
+    return int(np.sum(np.asarray(qc) != rc) + np.sum(np.asarray(qs) != rs))
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in a jaxpr, nested jaxprs included (jit
+    bodies, scans, a ``pallas_call``'s kernel)."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    names |= _primitives(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    names |= _primitives(sub)
+    return names
+
+
+class TestOneBitCodes:
+    """The 1-bit code from the phase's quadrant (no cos or sin) equals the
+    float64 signs of the same float32 phase."""
+
+    @pytest.mark.parametrize("sign", [1.0, -1.0])
+    @pytest.mark.parametrize("amp", [1.0, 10.0, 100.0])
+    def test_agrees_with_float64_signs(self, amp, sign):
+        rng = np.random.default_rng(int(amp) * 2 + (sign > 0))
+        theta = (sign * rng.uniform(0.0, amp, 500_000)).astype(np.float32)
+        # At most one disagreement per 10^6 codes (2 codes per phase).
+        assert _code_mismatches(theta) <= 1
+
+    @pytest.mark.parametrize("ulps", [-3, -2, -1, 1, 2, 3])
+    def test_agrees_a_few_ulps_from_multiples_of_half_pi(self, ulps):
+        ks = np.array([k for k in range(-4096, 4097) if k != 0])
+        theta = (ks * (np.pi / 2)).astype(np.float32)
+        toward = np.float32(np.inf if ulps > 0 else -np.inf)
+        for _ in range(abs(ulps)):
+            theta = np.nextafter(theta, toward)
+        assert _code_mismatches(theta) <= 2 * theta.size // 10**6
+
+    def test_exact_at_zero_and_nearest_multiples_of_half_pi(self):
+        """``0`` maps to +1 (as ``sign(sin 0)`` does), and at the float32
+        phase nearest ``k pi/2`` each code is the float64 sign there."""
+        ks = np.array([k for k in range(-16, 17) if k != 0])
+        theta = np.concatenate(
+            [np.float32([0.0, -0.0]), (ks * (np.pi / 2)).astype(np.float32)]
+        )
+        qc, qs = jax.jit(qz.one_bit_codes)(jnp.asarray(theta))
+        rc, rs = _f64_signs(theta)
+        np.testing.assert_array_equal(np.asarray(qc), rc)
+        np.testing.assert_array_equal(np.asarray(qs), rs)
+        assert np.asarray(qc)[:2].tolist() == np.asarray(qs)[:2].tolist() == [1, 1]
+
+    @pytest.mark.parametrize("path", ["xla", "pallas_dense", "pallas_structured"])
+    @pytest.mark.parametrize("bits", [1, 2])
+    def test_only_bbit_codes_compute_trig(self, path, bits):
+        """The 1-bit program holds no ``sin`` or ``cos`` on any backend; the
+        b-bit code still rounds ``S * cos`` and ``S * sin``."""
+        x = jnp.zeros((64, 5), jnp.float32)
+        dither = jnp.zeros((40,), jnp.float32)
+        if path == "xla":
+            fn = lambda x, d: qz.quantize_codes(x @ jnp.ones((5, 40)), d, bits)
+        else:
+            name = path.split("_")[1]
+            op = fo.make_operator(name, jax.random.PRNGKey(0), 40, 5, 1.0)
+            fn = lambda x, d: ops.quantized_fourier_sketch_sums(
+                x, op, d, bits=bits, block_n=64, block_m=128, interpret=True
+            )
+        prims = _primitives(jax.make_jaxpr(fn)(x, dither).jaxpr)
+        has_trig = bool({"sin", "cos"} & prims)
+        assert has_trig == (bits > 1), sorted(prims)
 
 
 class TestQuantizedMonoidLaws:
@@ -188,6 +272,23 @@ class TestQuantizedBackendParity:
             assert _int_state_equal(s_x, s_p), spec
             for za, zb in zip(e_x.finalize(s_x), e_p.finalize(s_p)):
                 np.testing.assert_allclose(np.asarray(za), np.asarray(zb))
+
+    @pytest.mark.parametrize("bits", [1, 2, 3])
+    @pytest.mark.parametrize("family", ["dense", "structured"])
+    def test_pallas_matches_xla_bitwise_per_depth(self, family, bits):
+        """Both fused kernels make ``quantize_codes``' codes at every depth.
+        2 bits is the depth whose scale (1) equals the 1-bit code's: its
+        codes are round(cos), not signs."""
+        x, _ = _data(3, npts=333, n=5)
+        op = fo.make_operator(family, jax.random.PRNGKey(4), 60, 5, 1.0)
+        q = _quantizer(3, 60, f"{bits}bit")
+        e_x = eng_mod.SketchEngine(op, "xla", quantizer=q)
+        e_p = eng_mod.SketchEngine(
+            op, "pallas", block_n=128, block_m=128, quantizer=q
+        )
+        s_x = e_x.update(e_x.init_state(), x)
+        s_p = e_p.update(e_p.init_state(), x)
+        assert _int_state_equal(s_x, s_p)
 
     def test_sharded_psums_integer_accumulators(self):
         """Acceptance: the sharded backend merges int accumulators (psum over
