@@ -22,6 +22,20 @@ Mergeable-state contract
                  CLOMPR box bounds ``(lower, upper)`` harvested in the same
                  pass.
 
+Where the state format lives
+----------------------------
+This module alone knows which leaves a state has and how each combines.  Its
+pure, shape-agnostic state functions are the only code that builds a state:
+``_identity`` (the monoid identity, any leading shape), ``_batch_partial``
+(one batch -> one undecayed partial, every backend), ``_lift`` and ``_tick``
+(a partial stamped at a tick; the empty-stamp rule), ``_merge_states`` with
+the ``_COMBINE`` table of each undecayed field's combine (sum / min / max),
+``_finalize`` (the flavour's finalize) and ``_check_capacity`` (the int32
+capacity check).  :class:`SketchEngine` calls them on one state;
+``core.fleet.FleetEngine`` vmaps (and, on a mesh, shard_maps) the same
+functions over a leading tenant axis.  A new state transform is therefore
+written here once, and the fleet inherits it.
+
 Backend matrix
 --------------
 =========  ==================================================================
@@ -240,6 +254,22 @@ def _decay_factor(gamma, dt):
     return jnp.where(dt > 0, gamma**safe, 1.0)
 
 
+# How each field of an undecayed state combines: the monoid's elementwise
+# rule, written once.  ``_merge_states``, the sharded backend's cross-device
+# reduction and the fleet's unique-id scatter all read it; the decayed twins
+# merge through ``_merge_states``'s own decay-aware branches.
+_COMBINE = {
+    "cos_acc": "sum",
+    "sin_acc": "sum",
+    "qcos_acc": "sum",
+    "qsin_acc": "sum",
+    "weight_sum": "sum",
+    "lower": "min",
+    "upper": "max",
+    "count": "sum",
+}
+
+
 @jax.jit
 def _merge_states(a, b):
     """Merge for either state flavour (dispatch happens at trace time)."""
@@ -294,48 +324,39 @@ def _merge_states(a, b):
             stamp=t,
             gamma=jnp.maximum(a.gamma, b.gamma),
         )
-    if isinstance(a, QuantizedSketchEngineState):
-        return QuantizedSketchEngineState(
-            qcos_acc=a.qcos_acc + b.qcos_acc,
-            qsin_acc=a.qsin_acc + b.qsin_acc,
-            weight_sum=a.weight_sum + b.weight_sum,
-            lower=jnp.minimum(a.lower, b.lower),
-            upper=jnp.maximum(a.upper, b.upper),
-            count=a.count + b.count,
+    return type(a)(
+        *(
+            topo._COMBINE[_COMBINE[f]](x, y)
+            for f, x, y in zip(a._fields, a, b)
         )
-    return SketchEngineState(
-        cos_acc=a.cos_acc + b.cos_acc,
-        sin_acc=a.sin_acc + b.sin_acc,
-        weight_sum=a.weight_sum + b.weight_sum,
-        lower=jnp.minimum(a.lower, b.lower),
-        upper=jnp.maximum(a.upper, b.upper),
-        count=a.count + b.count,
     )
+
+
+def _normalise(state, cos_acc, sin_acc):
+    """``(z, lower, upper)``: the stacked-real sums over the weight sum.
+
+    An empty stream (or an all-zero-weight shard) has nothing to average:
+    return the zero sketch rather than accumulator/denom garbage, float or
+    quantized.  The tiny denom floor alone is not enough — cos_acc can be
+    exactly 0 while a negative-weight cancellation leaves weight_sum at -0.0
+    or ~1e-38.
+    """
+    denom = jnp.maximum(state.weight_sum, 1e-30)
+    z = jnp.concatenate([cos_acc, -sin_acc]) / denom
+    z = jnp.where(state.weight_sum > 0, z, jnp.zeros_like(z))
+    return z, state.lower, state.upper
 
 
 @jax.jit
 def _finalize_state(state: SketchEngineState):
-    # An empty stream (or an all-zero-weight shard) has nothing to average:
-    # return the zero sketch rather than accumulator/denom garbage.  The tiny
-    # denom floor alone is not enough — cos_acc can be exactly 0 while a
-    # negative-weight cancellation leaves weight_sum at -0.0 or ~1e-38.
-    denom = jnp.maximum(state.weight_sum, 1e-30)
-    z = jnp.concatenate([state.cos_acc, -state.sin_acc]) / denom
-    z = jnp.where(state.weight_sum > 0, z, jnp.zeros_like(z))
-    return z, state.lower, state.upper
+    return _normalise(state, state.cos_acc, state.sin_acc)
 
 
 @functools.partial(jax.jit, static_argnames=("bits",))
 def _finalize_quantized(state: QuantizedSketchEngineState, dither, bits: int):
-    cos_acc, sin_acc = qz.dequantize_sums(
-        state.qcos_acc, state.qsin_acc, dither, bits
+    return _normalise(
+        state, *qz.dequantize_sums(state.qcos_acc, state.qsin_acc, dither, bits)
     )
-    denom = jnp.maximum(state.weight_sum, 1e-30)
-    z = jnp.concatenate([cos_acc, -sin_acc]) / denom
-    # Same zero-weight guard as the float path: an empty quantized stream
-    # must finalize to the zero sketch, never to code-sum / denom garbage.
-    z = jnp.where(state.weight_sum > 0, z, jnp.zeros_like(z))
-    return z, state.lower, state.upper
 
 
 @functools.partial(jax.jit, static_argnames=("bits",))
@@ -347,16 +368,247 @@ def _finalize_decayed_quantized(
     # code total directly.  With an empty side-channel this is bitwise equal
     # to ``_finalize_quantized``: ``q.astype(f32) + 0.0`` and the int path's
     # internal ``astype(f32)`` produce the same float.
-    cos_acc, sin_acc = qz.dequantize_sums(
-        state.qcos_acc.astype(jnp.float32) + state.dcos_acc,
-        state.qsin_acc.astype(jnp.float32) + state.dsin_acc,
-        dither,
-        bits,
+    return _normalise(
+        state,
+        *qz.dequantize_sums(
+            state.qcos_acc.astype(jnp.float32) + state.dcos_acc,
+            state.qsin_acc.astype(jnp.float32) + state.dsin_acc,
+            dither,
+            bits,
+        ),
     )
-    denom = jnp.maximum(state.weight_sum, 1e-30)
-    z = jnp.concatenate([cos_acc, -sin_acc]) / denom
-    z = jnp.where(state.weight_sum > 0, z, jnp.zeros_like(z))
-    return z, state.lower, state.upper
+
+
+def _finalize(state, dither=None, bits=None):
+    """The finalize of a state's flavour: dequantize with ``dither`` and
+    ``bits`` when the accumulators hold codes.  Shape-agnostic through
+    ``vmap``: the fleet maps it over its tenant rows."""
+    if isinstance(state, DecayedQuantizedSketchEngineState):
+        return _finalize_decayed_quantized(state, dither, bits)
+    if isinstance(state, QuantizedSketchEngineState):
+        return _finalize_quantized(state, dither, bits)
+    # ``_finalize_state`` duck-types over the float flavours — the decayed
+    # state has the same accumulator fields (jit retraces per pytree).
+    return _finalize_state(state)
+
+
+def _check_capacity(count, bits: int) -> None:
+    """Raise if int32 code sums may have wrapped.
+
+    They wrap silently once ``count * scale`` exceeds the int32 range, so
+    this reads the (non-wrapping) f32 ``count`` rather than garbage-decode:
+    one state's count, or a stacked fleet's, where its fullest row decides.
+    Skipped under tracing.  ``float`` waits for the device: one sync.
+    """
+    if isinstance(count, jax.core.Tracer):
+        return
+    folded = float(jnp.max(count) if jnp.ndim(count) else count)
+    cap = qz.accumulator_capacity(bits)
+    if folded > cap:
+        raise ValueError(
+            f"quantized accumulators overflow: {folded:.0f} points folded "
+            f"at {bits} bits exceeds the int32 capacity of {cap} points "
+            "(core.quantize.accumulator_capacity)"
+        )
+
+
+def _identity(n: int, m: int, *, quantized: bool, decay=None, lead=()):
+    """The monoid identity of a flavour, with leading axes ``lead`` (a
+    fleet's tenant axis): zero sums, ``+inf/-inf`` bounds and, under
+    ``decay``, the ``-inf`` stamp."""
+    f32 = jnp.float32
+    acc = jnp.int32 if quantized else f32
+    part = (QuantizedSketchEngineState if quantized else SketchEngineState)(
+        jnp.zeros(lead + (m,), acc),
+        jnp.zeros(lead + (m,), acc),
+        jnp.zeros(lead, f32),
+        jnp.full(lead + (n,), jnp.inf, f32),
+        jnp.full(lead + (n,), -jnp.inf, f32),
+        jnp.zeros(lead, f32),
+    )
+    if decay is None:
+        return part
+    return _lift(part, jnp.full(lead, -jnp.inf, f32), decay)
+
+
+def _lift(part, stamp, decay: float):
+    """An undecayed partial as its decayed twin stamped at tick ``stamp``
+    (any leading shape) — the bridge between the batch partials, which know
+    nothing about time, and the timestamped merge."""
+    stamp = jnp.asarray(stamp, jnp.float32)
+    gamma = jnp.full(jnp.shape(stamp), decay, jnp.float32)
+    if isinstance(part, QuantizedSketchEngineState):
+        return DecayedQuantizedSketchEngineState(
+            part.qcos_acc,
+            part.qsin_acc,
+            jnp.zeros_like(part.qcos_acc, jnp.float32),
+            jnp.zeros_like(part.qsin_acc, jnp.float32),
+            *part[2:],
+            stamp,
+            gamma,
+        )
+    return DecayedSketchEngineState(*part, stamp, gamma)
+
+
+def _tick(stamp, t=None):
+    """The tick a partial is lifted at, against a state clock ``stamp``.
+
+    ``t=None``, and every ``nan`` entry of ``t`` (the fleet's per-request
+    "my row's clock" sentinel), take the state's own clock: no time
+    advance, with the identity's ``-inf`` resolving to tick 0.  A partial
+    must never carry ``-inf`` itself: a non-empty contribution stamped
+    ``-inf`` would be decayed to nothing by any later merge.
+    """
+    if t is None:
+        return jnp.where(jnp.isfinite(stamp), stamp, 0.0)
+    return jnp.where(jnp.isnan(t), _tick(stamp), t)
+
+
+def _decay_to(state, identity, t):
+    """Advance a decayed state's clock to tick ``t`` without folding data,
+    inside the merge algebra: merge with ``identity`` stamped ``t``."""
+    stamp = jnp.broadcast_to(
+        jnp.asarray(t, jnp.float32), jnp.shape(identity.stamp)
+    )
+    return _merge_states(state, identity._replace(stamp=stamp))
+
+
+def _weights(weights, shape, quantized: bool):
+    """Per-point weights of ``shape`` points: unit when ``None``.  The
+    quantized transform counts points and takes none."""
+    if quantized:
+        if weights is not None:
+            raise ValueError(
+                "quantized sketch states accumulate unit-weight integer "
+                "counts; per-point weights are not representable"
+            )
+        return None
+    if weights is None:
+        return jnp.ones(shape, jnp.float32)
+    return jnp.asarray(weights, jnp.float32)
+
+
+def _xla_sums(x, op, dither, weights, *, bits, chunk, vary_axes=()):
+    """``(cos, sin)`` sums of one batch on XLA: float sums, or int32 code
+    sums (``weights`` then masks padding rows, or is ``None``)."""
+    chunk = min(chunk, max(x.shape[0], 1))
+    if bits is None:
+        part = sk.sketch(
+            x, op, weights=weights, chunk=chunk, vary_axes=vary_axes
+        )
+        return part[: op.m], -part[op.m :]
+    return sk.sketch_quantized(
+        x, op, dither, valid=weights, bits=bits, chunk=chunk,
+        vary_axes=vary_axes,
+    )
+
+
+def _batch_partial(
+    op,
+    dither,
+    x,
+    weights,
+    *,
+    backend: str,
+    bits: int | None,
+    chunk: int,
+    block_n: int,
+    block_m: int,
+    interpret: bool | None,
+    mesh: Mesh | None = None,
+    data_axes: tuple[str, ...] = (),
+    topology: str = "allreduce",
+):
+    """One batch ``x: (B, n)`` -> one undecayed partial state.
+
+    ``dither`` is the quantizer's (``bits`` set) or ``None``; ``weights``
+    default to 1 per point.  The keywords are static backend settings, so
+    the fleet can ``vmap`` a ``functools.partial`` of this over
+    ``(op, dither, x, weights)``.
+    """
+    weights = _weights(weights, x.shape[:1], bits is not None)
+    if backend == "sharded":
+        return _sharded_partial(
+            op, dither, x, weights, bits=bits, chunk=chunk, mesh=mesh,
+            axes=data_axes, topology=topology,
+        )
+    if backend == "pallas":
+        from repro.kernels import ops
+
+        tiles = dict(block_n=block_n, block_m=block_m, interpret=interpret)
+        if bits is None:
+            sums = ops.fourier_sketch_sums(x, op, weights, **tiles)
+        else:
+            sums = ops.quantized_fourier_sketch_sums(
+                x, op, dither, bits=bits, **tiles
+            )
+    else:  # xla
+        sums = _xla_sums(x, op, dither, weights, bits=bits, chunk=chunk)
+    count = jnp.asarray(x.shape[0], jnp.float32)
+    if bits is None:
+        return SketchEngineState(
+            *sums, jnp.sum(weights), jnp.min(x, axis=0), jnp.max(x, axis=0),
+            count,
+        )
+    # Quantized states count points: the weight sum is the count.
+    return QuantizedSketchEngineState(
+        *sums, count, jnp.min(x, axis=0), jnp.max(x, axis=0), count
+    )
+
+
+def _sharded_partial(op, dither, x, weights, *, bits, chunk, mesh, axes, topology):
+    """The sharded backend's partial: each device sketches its shard of the
+    batch, and one reduction per field (``_COMBINE``) merges them — O(m)
+    cross-device traffic, int32 code sums on the quantized path.
+
+    shard_map needs the leading axis divisible by the data-axis extent;
+    streaming batches (ragged tail chunks) generally aren't.  Pad with
+    zero-weight copies of the first row: weight 0 keeps the sums exact (on
+    the quantized path the weights are the mask of real rows), and a copied
+    point cannot move the min/max bounds.  The count is the unpadded one.
+    """
+    b = x.shape[0]
+    mask = jnp.ones((b,), jnp.float32) if weights is None else weights
+    pad = (-b) % axis_extent(mesh, axes)
+    if pad:
+        x = jnp.concatenate(
+            [x, jnp.broadcast_to(x[:1], (pad, x.shape[1]))], axis=0
+        )
+        mask = jnp.concatenate([mask, jnp.zeros((pad,), jnp.float32)], axis=0)
+    cls = SketchEngineState if bits is None else QuantizedSketchEngineState
+
+    def local(x_shard, op_rep, dither_rep, mask_shard):
+        sums = _xla_sums(
+            x_shard, op_rep, dither_rep, mask_shard, bits=bits, chunk=chunk,
+            vary_axes=axes,
+        )
+        leaves = (
+            *sums,
+            jnp.sum(mask_shard),
+            jnp.min(x_shard, axis=0),
+            jnp.max(x_shard, axis=0),
+        )
+        # The engine's monoid merge as a collective schedule: bitwise the
+        # same for every topology on the int32 path.
+        return tuple(
+            topo.axis_reduce(v, axes, topology, op=_COMBINE[f])
+            for f, v in zip(cls._fields, leaves)
+        )
+
+    # The operator rides shard_map as a replicated pytree: its leaves are
+    # what the broadcast ships — O(m) signs/radii for the structured family
+    # instead of the O(n·m) dense matrix.  tree/ring reductions return
+    # ppermute-derived values the VMA checker cannot see as replicated (they
+    # are — exactly for integers, to association-order ulps for floats), so
+    # checking is off for them; allreduce (psum) keeps it as a safety net.
+    fn = compat.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(axes), P(), P(), P(axes)),
+        out_specs=(P(),) * 5,
+        check_vma=False if topology != "allreduce" else None,
+    )
+    return cls(*fn(x, op, dither, mask), jnp.asarray(b, jnp.float32))
 
 
 class SketchEngine:
@@ -477,96 +729,29 @@ class SketchEngine:
 
     def init_state(self) -> SketchEngineState | QuantizedSketchEngineState:
         """The monoid identity: merge(init_state(), s) == s for any s."""
-        if self.decay is not None:
-            stamp = jnp.full((), -jnp.inf, jnp.float32)
-            gamma = jnp.full((), self.decay, jnp.float32)
-            if self.quantizer is not None:
-                return DecayedQuantizedSketchEngineState(
-                    qcos_acc=jnp.zeros((self.m,), jnp.int32),
-                    qsin_acc=jnp.zeros((self.m,), jnp.int32),
-                    dcos_acc=jnp.zeros((self.m,), jnp.float32),
-                    dsin_acc=jnp.zeros((self.m,), jnp.float32),
-                    weight_sum=jnp.zeros((), jnp.float32),
-                    lower=jnp.full((self.n,), jnp.inf, jnp.float32),
-                    upper=jnp.full((self.n,), -jnp.inf, jnp.float32),
-                    count=jnp.zeros((), jnp.float32),
-                    stamp=stamp,
-                    gamma=gamma,
-                )
-            return DecayedSketchEngineState(
-                cos_acc=jnp.zeros((self.m,), jnp.float32),
-                sin_acc=jnp.zeros((self.m,), jnp.float32),
-                weight_sum=jnp.zeros((), jnp.float32),
-                lower=jnp.full((self.n,), jnp.inf, jnp.float32),
-                upper=jnp.full((self.n,), -jnp.inf, jnp.float32),
-                count=jnp.zeros((), jnp.float32),
-                stamp=stamp,
-                gamma=gamma,
-            )
-        if self.quantizer is not None:
-            return QuantizedSketchEngineState(
-                qcos_acc=jnp.zeros((self.m,), jnp.int32),
-                qsin_acc=jnp.zeros((self.m,), jnp.int32),
-                weight_sum=jnp.zeros((), jnp.float32),
-                lower=jnp.full((self.n,), jnp.inf, jnp.float32),
-                upper=jnp.full((self.n,), -jnp.inf, jnp.float32),
-                count=jnp.zeros((), jnp.float32),
-            )
-        return SketchEngineState(
-            cos_acc=jnp.zeros((self.m,), jnp.float32),
-            sin_acc=jnp.zeros((self.m,), jnp.float32),
-            weight_sum=jnp.zeros((), jnp.float32),
-            lower=jnp.full((self.n,), jnp.inf, jnp.float32),
-            upper=jnp.full((self.n,), -jnp.inf, jnp.float32),
-            count=jnp.zeros((), jnp.float32),
-        )
-
-    def _lift_partial(self, part, t):
-        """Wrap a base (undecayed) batch partial as a decayed state at tick
-        ``t`` — the bridge between the backend batch kernels (which know
-        nothing about time) and the timestamped merge."""
-        stamp = jnp.asarray(t, jnp.float32)
-        gamma = jnp.full(jnp.shape(stamp), self.decay, jnp.float32)
-        if isinstance(part, QuantizedSketchEngineState):
-            return DecayedQuantizedSketchEngineState(
-                qcos_acc=part.qcos_acc,
-                qsin_acc=part.qsin_acc,
-                dcos_acc=jnp.zeros_like(part.qcos_acc, jnp.float32),
-                dsin_acc=jnp.zeros_like(part.qsin_acc, jnp.float32),
-                weight_sum=part.weight_sum,
-                lower=part.lower,
-                upper=part.upper,
-                count=part.count,
-                stamp=stamp,
-                gamma=gamma,
-            )
-        return DecayedSketchEngineState(
-            cos_acc=part.cos_acc,
-            sin_acc=part.sin_acc,
-            weight_sum=part.weight_sum,
-            lower=part.lower,
-            upper=part.upper,
-            count=part.count,
-            stamp=stamp,
-            gamma=gamma,
+        return _identity(
+            self.n, self.m, quantized=self.quantizer is not None,
+            decay=self.decay,
         )
 
     def _partial_state(self, batch: jax.Array, weights: jax.Array | None):
         """One batch -> one partial state (the pre-merge half of update)."""
-        x = jnp.asarray(batch, jnp.float32)
-        b = x.shape[0]
-        if self.quantizer is not None:
-            if weights is not None:
-                raise ValueError(
-                    "quantized sketch states accumulate unit-weight integer "
-                    "counts; per-point weights are not representable"
-                )
-            return self._quantized_batch_state(x)
-        if weights is None:
-            weights = jnp.ones((b,), jnp.float32)
-        else:
-            weights = jnp.asarray(weights, jnp.float32)
-        return self._batch_state(x, weights)
+        q = self.quantizer
+        return _batch_partial(
+            self.freq_op,
+            None if q is None else q.dither,
+            jnp.asarray(batch, jnp.float32),
+            weights,
+            backend=self.backend,
+            bits=None if q is None else q.bits,
+            chunk=self.chunk,
+            block_n=self.block_n,
+            block_m=self.block_m,
+            interpret=self.interpret,
+            mesh=self.mesh,
+            data_axes=self.data_axes,
+            topology=self.reduce_topology,
+        )
 
     def update(
         self,
@@ -593,33 +778,23 @@ class SketchEngine:
                 "(SketchEngine(decay=gamma))"
             )
         if not obs_rt.ENABLED:
-            part = self._partial_state(batch, weights)
-            if self.decay is not None:
-                part = self._lift_partial(part, self._resolve_t(state, t))
-            return _merge_states(state, part)
+            return self._update(state, batch, weights, t)
         from repro.obs import trace as obs_trace
 
         h = self._obs()
         with obs_trace.span("engine.update", backend=self.backend):
-            part = self._partial_state(batch, weights)
-            if self.decay is not None:
-                part = self._lift_partial(part, self._resolve_t(state, t))
-            out = _merge_states(state, part)
+            out = self._update(state, batch, weights, t)
         h.update_calls.inc()
         h.update_rows.inc(float(np.shape(batch)[0]))
         h.merge_calls.inc()
         h.state_bytes.set(_state_nbytes(out))
         return out
 
-    @staticmethod
-    def _resolve_t(state, t):
-        """``t=None`` -> the state's own stamp (no time advance), with the
-        identity's ``-inf`` stamp resolving to tick 0.  A partial must never
-        carry ``-inf`` itself: a non-empty contribution stamped -inf would be
-        decayed to nothing by any later merge."""
-        if t is not None:
-            return t
-        return jnp.where(jnp.isfinite(state.stamp), state.stamp, 0.0)
+    def _update(self, state, batch, weights, t):
+        part = self._partial_state(batch, weights)
+        if self.decay is not None:
+            part = _lift(part, _tick(state.stamp) if t is None else t, self.decay)
+        return _merge_states(state, part)
 
     def decay_to(self, state, t: float | jax.Array):
         """Advance a decayed state's clock to tick ``t`` without folding data:
@@ -635,11 +810,7 @@ class SketchEngine:
                 "decay_to requires a decay-enabled engine "
                 "(SketchEngine(decay=gamma))"
             )
-        empty = self.init_state()
-        stamp = jnp.broadcast_to(
-            jnp.asarray(t, jnp.float32), jnp.shape(empty.stamp)
-        )
-        return _merge_states(state, empty._replace(stamp=stamp))
+        return _decay_to(state, self.init_state(), t)
 
     def merge(self, a, b):
         """Associative + commutative combine of two partial states."""
@@ -684,36 +855,15 @@ class SketchEngine:
         return out
 
     def _finalize_impl(self, state):
-        if self.quantizer is not None:
-            from repro.obs import trace as obs_trace
+        q = self.quantizer
+        if q is None:
+            return _finalize(state)
+        from repro.obs import trace as obs_trace
 
-            # The capacity check and the dequantization, under one span.
-            with obs_trace.span("engine.dequantize", bits=self.quantizer.bits):
-                # int32 code sums wrap silently once count * scale exceeds
-                # the int32 range — detect post-hoc from the (non-wrapping)
-                # f32 count rather than garbage-decode.  Skipped under
-                # tracing.  ``float`` waits for the device: one sync.
-                cap = qz.accumulator_capacity(self.quantizer.bits)
-                if not isinstance(state.count, jax.core.Tracer) and float(
-                    state.count
-                ) > cap:
-                    raise ValueError(
-                        f"quantized accumulators overflow: "
-                        f"{float(state.count):.0f} points folded at "
-                        f"{self.quantizer.bits} bits exceeds the int32 "
-                        f"capacity of {cap} points "
-                        "(core.quantize.accumulator_capacity)"
-                    )
-                if isinstance(state, DecayedQuantizedSketchEngineState):
-                    return _finalize_decayed_quantized(
-                        state, self.quantizer.dither, self.quantizer.bits
-                    )
-                return _finalize_quantized(
-                    state, self.quantizer.dither, self.quantizer.bits
-                )
-        # ``_finalize_state`` duck-types over the float flavours — the decayed
-        # state has the same accumulator fields (jit retraces per pytree).
-        return _finalize_state(state)
+        # The capacity check and the dequantization, under one span.
+        with obs_trace.span("engine.dequantize", bits=q.bits):
+            _check_capacity(state.count, q.bits)
+            return _finalize(state, q.dither, q.bits)
 
     # -- conveniences -------------------------------------------------------
 
@@ -745,186 +895,7 @@ class SketchEngine:
             state = self.update(state, batch)
         return self.finalize(state)
 
-    # -- backend dispatch ---------------------------------------------------
-
-    def _check_vma(self) -> bool | None:
-        """Replication-checker setting for the sharded backend's shard_map.
-
-        tree/ring reductions return ppermute-derived values the VMA checker
-        cannot see as replicated (they are — exactly for integers, to
-        association-order ulps for floats), so newer-JAX checking must be
-        off for them; the default allreduce (psum) keeps the checker at its
-        default as a safety net for future body edits.
-        """
-        return False if self.reduce_topology != "allreduce" else None
-
-    def _batch_state(self, x: jax.Array, weights: jax.Array) -> SketchEngineState:
-        if self.backend == "sharded":
-            return self._sharded_batch_state(x, weights)
-        if self.backend == "pallas":
-            from repro.kernels import ops
-
-            cos_s, sin_s = ops.fourier_sketch_sums(
-                x,
-                self.freq_op,
-                weights,
-                block_n=self.block_n,
-                block_m=self.block_m,
-                interpret=self.interpret,
-            )
-        else:  # xla
-            part = sk.sketch(
-                x,
-                self.freq_op,
-                weights=weights,
-                chunk=min(self.chunk, max(x.shape[0], 1)),
-            )
-            cos_s, sin_s = part[: self.m], -part[self.m :]
-        return SketchEngineState(
-            cos_acc=cos_s,
-            sin_acc=sin_s,
-            weight_sum=jnp.sum(weights),
-            lower=jnp.min(x, axis=0),
-            upper=jnp.max(x, axis=0),
-            count=jnp.asarray(x.shape[0], jnp.float32),
-        )
-
-    def _quantized_batch_state(self, x: jax.Array) -> QuantizedSketchEngineState:
-        q = self.quantizer
-        if self.backend == "sharded":
-            return self._sharded_quantized_batch_state(x)
-        if self.backend == "pallas":
-            from repro.kernels import ops
-
-            qcos, qsin = ops.quantized_fourier_sketch_sums(
-                x,
-                self.freq_op,
-                q.dither,
-                bits=q.bits,
-                block_n=self.block_n,
-                block_m=self.block_m,
-                interpret=self.interpret,
-            )
-        else:  # xla
-            qcos, qsin = sk.sketch_quantized(
-                x,
-                self.freq_op,
-                q.dither,
-                bits=q.bits,
-                chunk=min(self.chunk, max(x.shape[0], 1)),
-            )
-        n_pts = jnp.asarray(x.shape[0], jnp.float32)
-        return QuantizedSketchEngineState(
-            qcos_acc=qcos,
-            qsin_acc=qsin,
-            weight_sum=n_pts,
-            lower=jnp.min(x, axis=0),
-            upper=jnp.max(x, axis=0),
-            count=n_pts,
-        )
-
-    def _sharded_quantized_batch_state(self, x: jax.Array) -> QuantizedSketchEngineState:
-        """Bandwidth-aware sharded path: psum **integer** accumulators.
-
-        Same ragged-batch strategy as the float path (pad with copies of the
-        first row, masked out), but the cross-device merge moves int32 code
-        sums instead of float sketches — the O(m) traffic the quantized
-        subsystem exists to shrink.
-        """
-        q = self.quantizer
-        axes = self.data_axes
-        chunk = self.chunk
-        topology = self.reduce_topology
-        b = x.shape[0]
-        pad = (-b) % axis_extent(self.mesh, axes)
-        valid = jnp.ones((b,), jnp.float32)
-        if pad:
-            x = jnp.concatenate(
-                [x, jnp.broadcast_to(x[:1], (pad, x.shape[1]))], axis=0
-            )
-            valid = jnp.concatenate([valid, jnp.zeros((pad,), jnp.float32)], axis=0)
-
-        def local(x_shard, op_rep, dither_rep, valid_shard):
-            qcos, qsin = sk.sketch_quantized(
-                x_shard,
-                op_rep,
-                dither_rep,
-                valid=valid_shard,
-                bits=q.bits,
-                chunk=min(chunk, max(x_shard.shape[0], 1)),
-                vary_axes=axes,
-            )
-            # Cross-device merge of the int32 code sums through the selected
-            # topology — the engine's monoid `merge` expressed as a
-            # collective schedule (bitwise identical for every topology).
-            qcos = topo.axis_reduce(qcos, axes, topology)
-            qsin = topo.axis_reduce(qsin, axes, topology)
-            cnt = topo.axis_reduce(jnp.sum(valid_shard), axes, topology)
-            lo = topo.axis_reduce(jnp.min(x_shard, axis=0), axes, topology, op="min")
-            hi = topo.axis_reduce(jnp.max(x_shard, axis=0), axes, topology, op="max")
-            return qcos, qsin, cnt, lo, hi
-
-        # The operator rides shard_map as a replicated pytree: its leaves are
-        # what the broadcast ships — O(m) signs/radii for the structured
-        # family instead of the O(n·m) dense matrix.
-        fn = compat.shard_map(
-            local,
-            mesh=self.mesh,
-            in_specs=(P(axes), P(), P(), P(axes)),
-            out_specs=(P(), P(), P(), P(), P()),
-            check_vma=self._check_vma(),
-        )
-        qcos, qsin, cnt, lo, hi = fn(x, self.freq_op, q.dither, valid)
-        return QuantizedSketchEngineState(
-            qcos, qsin, cnt, lo, hi, jnp.asarray(b, jnp.float32)
-        )
-
-    def _sharded_batch_state(self, x: jax.Array, weights: jax.Array) -> SketchEngineState:
-        axes = self.data_axes
-        chunk = self.chunk
-        topology = self.reduce_topology
-        b = x.shape[0]
-        # shard_map needs the leading axis divisible by the data-axis extent;
-        # streaming batches (ragged tail chunks) generally aren't.  Pad with
-        # zero-weight copies of the first row: weight 0 keeps the sums exact
-        # and a copied point cannot move the min/max bounds.  True count is
-        # taken from the unpadded batch below.
-        pad = (-b) % axis_extent(self.mesh, axes)
-        if pad:
-            x = jnp.concatenate(
-                [x, jnp.broadcast_to(x[:1], (pad, x.shape[1]))], axis=0
-            )
-            weights = jnp.concatenate(
-                [weights, jnp.zeros((pad,), jnp.float32)], axis=0
-            )
-
-        def local(x_shard, op_rep, wt_shard):
-            part = sk.sketch(
-                x_shard,
-                op_rep,
-                weights=wt_shard,
-                chunk=min(chunk, max(x_shard.shape[0], 1)),
-                vary_axes=axes,
-            )
-            m = op_rep.m
-            cos_s = topo.axis_reduce(part[:m], axes, topology)
-            sin_s = topo.axis_reduce(-part[m:], axes, topology)
-            wsum = topo.axis_reduce(jnp.sum(wt_shard), axes, topology)
-            lo = topo.axis_reduce(jnp.min(x_shard, axis=0), axes, topology, op="min")
-            hi = topo.axis_reduce(jnp.max(x_shard, axis=0), axes, topology, op="max")
-            return cos_s, sin_s, wsum, lo, hi
-
-        fn = compat.shard_map(
-            local,
-            mesh=self.mesh,
-            in_specs=(P(axes), P(), P(axes)),
-            out_specs=(P(), P(), P(), P(), P()),
-            check_vma=self._check_vma(),
-        )
-        cos_s, sin_s, wsum, lo, hi = fn(x, self.freq_op, weights)
-        return SketchEngineState(
-            cos_s, sin_s, wsum, lo, hi, jnp.asarray(b, jnp.float32)
-        )
+    # -- placement ---------------------------------------------------------
 
     def shard_points(self, x: jax.Array) -> jax.Array:
         """Place ``x`` with its leading axis sharded over the data axes.

@@ -12,13 +12,21 @@ fleet instead of T Python-dispatched engine calls.
 
 Contract: the vmapped monoid law
 --------------------------------
-For every tenant t, ``FleetEngine`` update/merge/finalize is **bitwise
-identical** to a per-tenant :class:`~repro.core.engine.SketchEngine` with the
-same operator/quantizer — the stacked path batches the *same* per-tenant
-trace (`tests/test_fleet.py` pins this for float and quantized states on the
-xla and pallas backends).  Everything the single-sketch stack guarantees
+The fleet has no state code of its own: the state format, the batch
+partials, the lift to a tick, the merge and the finalize are
+``core.engine``'s state functions, vmapped over the tenant axis (and
+shard_mapped on a mesh).  For every tenant t, ``FleetEngine``
+update/merge/finalize therefore runs the *same* per-tenant trace as a
+:class:`~repro.core.engine.SketchEngine` with the same operator/quantizer,
+batched.  ``tests/test_fleet.py`` pins row t **bitwise** equal to that
+engine for float and 1-bit states, undecayed and decayed, on the xla and
+pallas backends, at a batch of 12 points per tenant.  Batching may change
+the association of a float reduction: on the CPU's float XLA path, rows at
+64 points per tenant differ from the isolated engine by up to ~1.5e-5 in
+``cos_acc`` (one float32 ulp at its magnitude), while 1-bit states and the
+Pallas kernel stay bitwise.  Everything the single-sketch stack guarantees
 (split invariance, merge associativity/commutativity, quantized bitwise
-merges) therefore lifts to the fleet for free.
+merges) lifts to the fleet up to that rounding.
 
 Request routing: segment-scatter
 --------------------------------
@@ -72,13 +80,6 @@ import numpy as np
 from repro.core import engine as eng_mod
 from repro.core import freq_ops as fo
 from repro.core import quantize as qz
-from repro.core import sketch as sk
-from repro.core.engine import (
-    DecayedQuantizedSketchEngineState,
-    DecayedSketchEngineState,
-    QuantizedSketchEngineState,
-    SketchEngineState,
-)
 
 __all__ = [
     "FLEET_BACKENDS",
@@ -396,219 +397,102 @@ class FleetEngine:
 
     def init_state(self):
         """Stacked monoid identity: every tenant row is ``init_state()``."""
-        t, n, m = self.n_tenants, self.n, self.m
+        return self.place_state(
+            eng_mod._identity(
+                self.n, self.m, quantized=self.quantized, decay=self.decay,
+                lead=(self.n_tenants,),
+            )
+        )
+
+    def _parts(self, op, x, aux):
+        """Vmapped batch partials of stacked ``(R, B, n)`` batches, one
+        operator row each; ``aux`` is the rows' dither (quantized) or
+        weights (float; ``None`` = unit)."""
         if self.quantized:
-            base = QuantizedSketchEngineState(
-                qcos_acc=jnp.zeros((t, m), jnp.int32),
-                qsin_acc=jnp.zeros((t, m), jnp.int32),
-                weight_sum=jnp.zeros((t,), jnp.float32),
-                lower=jnp.full((t, n), jnp.inf, jnp.float32),
-                upper=jnp.full((t, n), -jnp.inf, jnp.float32),
-                count=jnp.zeros((t,), jnp.float32),
-            )
+            dither, weights = aux, None
         else:
-            base = SketchEngineState(
-                cos_acc=jnp.zeros((t, m), jnp.float32),
-                sin_acc=jnp.zeros((t, m), jnp.float32),
-                weight_sum=jnp.zeros((t,), jnp.float32),
-                lower=jnp.full((t, n), jnp.inf, jnp.float32),
-                upper=jnp.full((t, n), -jnp.inf, jnp.float32),
-                count=jnp.zeros((t,), jnp.float32),
-            )
-        if self.decay is not None:
-            base = self._lift_parts(
-                base, jnp.full((t,), -jnp.inf, jnp.float32)
-            )
-        return self.place_state(base)
-
-    def _lift_parts(self, parts, stamps):
-        """Wrap stacked base partials as decayed states stamped ``stamps``
-        (``(R,)`` — one tick per row), mirroring
-        ``SketchEngine._lift_partial``."""
-        stamps = jnp.asarray(stamps, jnp.float32)
-        gamma = jnp.full(jnp.shape(stamps), self.decay, jnp.float32)
-        if isinstance(parts, QuantizedSketchEngineState):
-            return DecayedQuantizedSketchEngineState(
-                qcos_acc=parts.qcos_acc,
-                qsin_acc=parts.qsin_acc,
-                dcos_acc=jnp.zeros_like(parts.qcos_acc, jnp.float32),
-                dsin_acc=jnp.zeros_like(parts.qsin_acc, jnp.float32),
-                weight_sum=parts.weight_sum,
-                lower=parts.lower,
-                upper=parts.upper,
-                count=parts.count,
-                stamp=stamps,
-                gamma=gamma,
-            )
-        return DecayedSketchEngineState(
-            cos_acc=parts.cos_acc,
-            sin_acc=parts.sin_acc,
-            weight_sum=parts.weight_sum,
-            lower=parts.lower,
-            upper=parts.upper,
-            count=parts.count,
-            stamp=stamps,
-            gamma=gamma,
+            dither, weights = None, eng_mod._weights(aux, x.shape[:2], False)
+        partial = functools.partial(
+            eng_mod._batch_partial,
+            backend=self.backend,
+            bits=self.bits,
+            chunk=self.chunk,
+            block_n=self.block_n,
+            block_m=self.block_m,
+            interpret=self.interpret,
         )
+        return jax.vmap(partial)(op, dither, x, weights)
 
-    def _tenant_part(self, op, x, weights):
-        """One tenant's batch partial — the SAME trace SketchEngine._batch_state
-        runs, factored over the operator argument so vmap can batch it."""
-        if self.backend == "pallas":
-            from repro.kernels import ops
-
-            cos_s, sin_s = ops.fourier_sketch_sums(
-                x,
-                op,
-                weights,
-                block_n=self.block_n,
-                block_m=self.block_m,
-                interpret=self.interpret,
-            )
-        else:
-            part = sk.sketch(
-                x,
-                op,
-                weights=weights,
-                chunk=min(self.chunk, max(x.shape[0], 1)),
-            )
-            cos_s, sin_s = part[: self.m], -part[self.m :]
-        return SketchEngineState(
-            cos_acc=cos_s,
-            sin_acc=sin_s,
-            weight_sum=jnp.sum(weights),
-            lower=jnp.min(x, axis=0),
-            upper=jnp.max(x, axis=0),
-            count=jnp.asarray(x.shape[0], jnp.float32),
-        )
-
-    def _tenant_qpart(self, op, dither, x):
-        if self.backend == "pallas":
-            from repro.kernels import ops
-
-            qcos, qsin = ops.quantized_fourier_sketch_sums(
-                x,
-                op,
-                dither,
-                bits=self.bits,
-                block_n=self.block_n,
-                block_m=self.block_m,
-                interpret=self.interpret,
-            )
-        else:
-            qcos, qsin = sk.sketch_quantized(
-                x,
-                op,
-                dither,
-                bits=self.bits,
-                chunk=min(self.chunk, max(x.shape[0], 1)),
-            )
-        n_pts = jnp.asarray(x.shape[0], jnp.float32)
-        return QuantizedSketchEngineState(
-            qcos_acc=qcos,
-            qsin_acc=qsin,
-            weight_sum=n_pts,
-            lower=jnp.min(x, axis=0),
-            upper=jnp.max(x, axis=0),
-            count=n_pts,
-        )
-
-    def _parts(self, stacked_op, batches, weights):
-        """Vmapped per-tenant partial states for stacked ``(R, B, n)`` batches."""
+    def _operands(self, batches, weights, *, fill: bool = False):
+        """``(x, aux)`` of a stacked ``(R, B, n)`` block: ``aux`` is the
+        dither stack (quantized: no weights) or the weights — ``None`` for
+        unit weights unless ``fill`` (a shard_map operand is an array)."""
         x = jnp.asarray(batches, jnp.float32)
         if x.ndim != 3 or x.shape[-1] != self.n:
             raise ValueError(
                 f"batches must be (T, B, {self.n}), got {x.shape}"
             )
         if self.quantized:
-            if weights is not None:
-                raise ValueError(
-                    "quantized fleet states accumulate unit-weight integer "
-                    "counts; per-point weights are not representable"
-                )
-            return jax.vmap(self._tenant_qpart)(stacked_op, self.dither, x)
-        if weights is None:
-            weights = jnp.ones(x.shape[:2], jnp.float32)
-        else:
-            weights = jnp.asarray(weights, jnp.float32)
-        return jax.vmap(self._tenant_part)(stacked_op, x, weights)
+            eng_mod._weights(weights, None, True)  # raises on any weights
+            return x, self.dither
+        if fill:
+            weights = eng_mod._weights(weights, x.shape[:2], False)
+        return x, weights
 
-    # -- mesh-sharded hot path ----------------------------------------------
-
-    def _row_specs(self, tree):
-        """``P(tenant_shard_axis)`` per leaf — every fleet leaf leads with
-        the tenant axis (same rule as ``parallel.sharding.tenant_shard_specs``,
-        inlined to keep this module importable without the parallel pkg)."""
-        row = jax.sharding.PartitionSpec(self.tenant_shard_axis)
-        return jax.tree_util.tree_map(lambda _: row, tree)
-
-    def _mesh_update_fn(self, state):
-        """The shard-mapped update, built once per engine: each device runs
-        the SAME vmapped per-tenant trace over its contiguous block of rows
-        (so row t is bitwise the unsharded row t), and no collective ever
-        enters the program — tenants are independent."""
-        if self._mesh_update_jit is not None:
-            return self._mesh_update_jit
+    def _shard_mapped(self, body, n_args: int):
+        """``body`` shard_mapped over the tenant axis and jitted: each device
+        runs it over its own contiguous block of rows, with no collective.
+        Every operand and output leaf leads with the tenant axis, so one
+        ``P(tenant_shard_axis)`` prefix covers each whole pytree."""
         from repro.utils import compat
 
-        quantized, decayed = self.quantized, self.decay is not None
-
-        def body(st, op, x, aux, *stamps):
-            if quantized:
-                parts = jax.vmap(self._tenant_qpart)(op, aux, x)
-            else:
-                parts = jax.vmap(self._tenant_part)(op, x, aux)
-            if decayed:
-                parts = self._lift_parts(parts, stamps[0])
-            return eng_mod._merge_states(st, parts)
-
         row = jax.sharding.PartitionSpec(self.tenant_shard_axis)
-        in_specs = (
-            self._row_specs(state),
-            self._row_specs(self._stacked_op),
-            row,
-            row,
-        ) + ((row,) if decayed else ())
-        fn = compat.shard_map(
-            body,
-            self.mesh,
-            in_specs=in_specs,
-            out_specs=self._row_specs(state),
-            check_vma=False,
+        return jax.jit(
+            compat.shard_map(
+                body, self.mesh, in_specs=(row,) * n_args, out_specs=row,
+                check_vma=False,
+            )
         )
-        self._mesh_update_jit = jax.jit(fn)
+
+    # -- update ---------------------------------------------------------------
+
+    def _update_body(self, st, op, x, aux, *t):
+        """The update, ``(state, op, x, aux[, t]) -> state``: run on the whole
+        stack, or shard-mapped on each block (so row t is bitwise the
+        unsharded row t).  ``t``: the rows' ticks; none = their own clocks."""
+        parts = self._parts(op, x, aux)
+        if self.decay is not None:
+            parts = eng_mod._lift(
+                parts, self._update_stamps(st, *t), self.decay
+            )
+        return eng_mod._merge_states(st, parts)
+
+    @staticmethod
+    def _update_stamps(state, t=None):
+        """Per-row ticks of an update: ``t`` over the rows, or each row's own
+        clock (empty rows resolve to tick 0)."""
+        if t is None:
+            return eng_mod._tick(state.stamp)
+        return jnp.broadcast_to(jnp.asarray(t, jnp.float32), state.stamp.shape)
+
+    def _mesh_update_fn(self, state):
+        """The shard-mapped update, built once per engine (``state`` is not
+        read: one tenant-axis spec covers every operand)."""
+        if self._mesh_update_jit is None:
+            self._mesh_update_jit = self._shard_mapped(
+                self._update_body, 4 + (self.decay is not None)
+            )
         return self._mesh_update_jit
 
     def _mesh_update_args(self, state, batches, weights, t):
         """Validated ``(jitted_fn, operands)`` of the mesh update — shared by
-        :meth:`update` and :meth:`mesh_update_hlo`."""
-        x = jnp.asarray(batches, jnp.float32)
-        if x.ndim != 3 or x.shape[-1] != self.n:
-            raise ValueError(
-                f"batches must be (T, B, {self.n}), got {x.shape}"
-            )
-        if self.quantized:
-            if weights is not None:
-                raise ValueError(
-                    "quantized fleet states accumulate unit-weight integer "
-                    "counts; per-point weights are not representable"
-                )
-            aux = self.dither  # (T, m), placed with its tenants
-        elif weights is None:
-            aux = jnp.ones(x.shape[:2], jnp.float32)
-        else:
-            aux = jnp.asarray(weights, jnp.float32)
-        operands = (state, self._stacked_op, x, aux)
+        :meth:`update` and :meth:`mesh_update_hlo`.  The ticks are resolved
+        here: every shard_map operand is a tenant-sharded array."""
+        operands = (state, self._stacked_op) + self._operands(
+            batches, weights, fill=True
+        )
         if self.decay is not None:
-            if t is None:
-                stamps = jnp.where(
-                    jnp.isfinite(state.stamp), state.stamp, 0.0
-                )
-            else:
-                stamps = jnp.broadcast_to(
-                    jnp.asarray(t, jnp.float32), (self.n_tenants,)
-                )
-            operands += (stamps,)
+            operands += (self._update_stamps(state, t),)
         return self._mesh_update_fn(state), operands
 
     def mesh_update_hlo(self, state, batches, weights=None, *, t=None) -> str:
@@ -640,100 +524,46 @@ class FleetEngine:
         if self.sharding == "mesh":
             fn, operands = self._mesh_update_args(state, batches, weights, t)
             return fn(*operands)
-        parts = self._parts(self._stacked_op, batches, weights)
-        if self.decay is not None:
-            if t is None:
-                stamps = jnp.where(
-                    jnp.isfinite(state.stamp), state.stamp, 0.0
-                )
-            else:
-                stamps = jnp.broadcast_to(
-                    jnp.asarray(t, jnp.float32), (self.n_tenants,)
-                )
-            parts = self._lift_parts(parts, stamps)
-        return eng_mod._merge_states(state, parts)
+        x, aux = self._operands(batches, weights)
+        ticks = () if t is None else (t,)
+        return self._update_body(state, self._stacked_op, x, aux, *ticks)
 
     def merge(self, a, b):
         """Stacked associative+commutative combine (elementwise, so the
         single-engine merge applies to (T, …) leaves unchanged)."""
         return eng_mod._merge_states(a, b)
 
+    # -- finalize -------------------------------------------------------------
+
+    def _finalize_body(self, st, dither):
+        """The vmapped finalize of every row of ``st`` — the shard_map body
+        reuses it verbatim, which is what keeps sharded finalize bitwise."""
+        fin = functools.partial(eng_mod._finalize, bits=self.bits)
+        return jax.vmap(fin)(st, dither)
+
     def finalize(self, state):
         """-> ``(z (T, 2m), lower (T, n), upper (T, n))``, all tenants.
         With ``sharding="mesh"`` the vmapped finalize runs within each
         shard (shard-mapped, no collectives); outputs stay tenant-sharded.
         """
-        self._check_capacity(state)
-        if self.sharding == "mesh":
-            return self._mesh_finalize_fn(state)(state)
-        return self._finalize_vmapped(state)
-
-    def _finalize_vmapped(self, state):
-        """The vmapped whole-fleet finalize — the shard_map body reuses it
-        verbatim, which is what keeps sharded finalize bitwise."""
         if self.quantized:
-            fin = (
-                eng_mod._finalize_decayed_quantized
-                if isinstance(state, DecayedQuantizedSketchEngineState)
-                else eng_mod._finalize_quantized
-            )
-            return jax.vmap(functools.partial(fin, bits=self.bits))(
-                state, self.dither
-            )
-        return jax.vmap(eng_mod._finalize_state)(state)
-
-    def _mesh_finalize_fn(self, state):
-        if self._mesh_finalize_jit is not None:
-            return self._mesh_finalize_jit
-        from repro.utils import compat
-
-        quantized = self.quantized
-
-        def body(st, *dither):
-            if quantized:
-                fin = (
-                    eng_mod._finalize_decayed_quantized
-                    if isinstance(st, DecayedQuantizedSketchEngineState)
-                    else eng_mod._finalize_quantized
+            eng_mod._check_capacity(state.count, self.bits)
+        if self.sharding == "mesh":
+            if self._mesh_finalize_jit is None:
+                self._mesh_finalize_jit = self._shard_mapped(
+                    self._finalize_body, 2
                 )
-                z, lo, hi = jax.vmap(
-                    functools.partial(fin, bits=self.bits)
-                )(st, dither[0])
-            else:
-                z, lo, hi = jax.vmap(eng_mod._finalize_state)(st)
-            return z, lo, hi
+            return self._mesh_finalize_jit(state, self.dither)
+        return self._finalize_body(state, self.dither)
 
-        row = jax.sharding.PartitionSpec(self.tenant_shard_axis)
-        in_specs = (self._row_specs(state),) + (
-            (row,) if quantized else ()
-        )
-        fn = compat.shard_map(
-            body,
-            self.mesh,
-            in_specs=in_specs,
-            out_specs=(row, row, row),
-            check_vma=False,
-        )
-        jitted = jax.jit(fn)
-        if quantized:
-            dither = self.dither
-            self._mesh_finalize_jit = lambda st: jitted(st, dither)
-        else:
-            self._mesh_finalize_jit = jitted
-        return self._mesh_finalize_jit
-
-    def _check_capacity(self, state):
+    def finalize_tenant(self, state, tenant: int):
+        """Finalize ONE tenant — O(m), the decode-on-demand hot path (the
+        full-fleet :meth:`finalize` is O(T·m))."""
+        row = self.tenant_state(state, tenant)
         if not self.quantized:
-            return
-        cap = qz.accumulator_capacity(self.bits)
-        if not isinstance(state.count, jax.core.Tracer) and float(
-            jnp.max(state.count)
-        ) > cap:
-            raise ValueError(
-                f"quantized fleet accumulators overflow: a tenant folded "
-                f"{float(jnp.max(state.count)):.0f} points at {self.bits} "
-                f"bits, over the int32 capacity of {cap}"
-            )
+            return eng_mod._finalize(row)
+        eng_mod._check_capacity(state.count, self.bits)
+        return eng_mod._finalize(row, self.dither[tenant], self.bits)
 
     # -- request routing: segment-scatter -----------------------------------
 
@@ -747,7 +577,8 @@ class FleetEngine:
         carrying duplicate ids (several requests for one tenant in a flush)
         take an ordered ``lax.scan`` fold so the tenant's float partials
         combine in arrival order — the same association its isolated engine
-        uses, preserving bitwise tenant isolation.
+        uses, preserving bitwise tenant isolation.  Ids that are not
+        concrete on the host (traced) always take the scan fold.
 
         Under ``decay``, ``t`` is the requests' tick — a scalar or ``(R,)``
         per request — and the fold ALWAYS takes the ordered scan path: the
@@ -762,74 +593,68 @@ class FleetEngine:
                 "(FleetEngine(..., decay=gamma))"
             )
         ids = jnp.asarray(tenant_ids, jnp.int32)
-        if ids.ndim != 1 or ids.shape[0] != jnp.asarray(batches).shape[0]:
+        x, aux = self._operands(
+            batches, weights, fill=self.sharding == "mesh"
+        )
+        if ids.ndim != 1 or ids.shape[0] != x.shape[0]:
             raise ValueError(
                 f"tenant_ids {ids.shape} must be (R,) matching batches "
-                f"{jnp.asarray(batches).shape}"
+                f"{x.shape}"
             )
+        host = (
+            None
+            if isinstance(tenant_ids, jax.core.Tracer)
+            else np.asarray(tenant_ids, np.int64)
+        )
+        unique = host is not None and np.unique(host).size == host.size
         if self.sharding == "mesh":
-            return self._mesh_ingest(state, tenant_ids, batches, weights, t)
-        gathered = jax.tree_util.tree_map(
-            lambda l: l[ids], self._stacked_op
+            return self._mesh_ingest(state, host, x, aux, t, unique)
+        ticks = () if t is None else (t,)
+        return self._ingest_body(
+            state, self._stacked_op, ids, x, aux, *ticks, unique=unique
         )
-        if self.quantized:
-            x = jnp.asarray(batches, jnp.float32)
-            if weights is not None:
-                raise ValueError(
-                    "quantized fleet states accumulate unit-weight integer "
-                    "counts; per-point weights are not representable"
-                )
-            parts = jax.vmap(self._tenant_qpart)(
-                gathered, self.dither[ids], x
-            )
-        else:
-            parts = self._parts(gathered, batches, weights)
 
+    def _ingest_body(self, st, op, ids, x, aux, *t, unique, rows=None):
+        """The request fold: gather each request's operator (and dither) by
+        its row id, compute the partials in one vmapped pass, and fold them
+        in — one scatter per leaf for unique ids, else in arrival order.
+        ``rows``: the block's row count, when slots with id ``rows`` are
+        padding that every fold skips (a mesh shard's requests)."""
+        valid = None if rows is None else ids < rows
+        safe = ids if rows is None else jnp.where(valid, ids, 0)
+        gathered = jax.tree_util.tree_map(lambda l: l[safe], op)
+        parts = self._parts(gathered, x, aux[safe] if self.quantized else aux)
         if self.decay is not None:
-            # nan = "stamp me with my row's clock" — resolved per request in
-            # the scan fold.  (-inf cannot be the sentinel: a non-empty
-            # partial stamped -inf would decay to nothing on merge.)
-            if t is None:
-                stamps = jnp.full((ids.shape[0],), jnp.nan, jnp.float32)
-            else:
-                stamps = jnp.broadcast_to(
-                    jnp.asarray(t, jnp.float32), (ids.shape[0],)
-                )
-            parts = self._lift_parts(parts, stamps)
-            return self._scan_parts(state, ids, parts)
-
-        unique = not isinstance(ids, jax.core.Tracer) and (
-            len(set(int(i) for i in ids)) == ids.shape[0]
-        )
+            stamps = self._request_stamps(ids.shape, *t)
+            parts = eng_mod._lift(parts, stamps, self.decay)
+            return self._scan_parts(st, safe, parts, valid)
         if unique:
-            return self._scatter_parts(state, ids, parts)
-        return self._scan_parts(state, ids, parts)
+            mode = None if rows is None else "drop"
+            return self._scatter_parts(st, ids, parts, mode=mode)
+        return self._scan_parts(st, safe, parts, valid)
+
+    @staticmethod
+    def _request_stamps(shape, t=None):
+        """Per-request ticks: ``t`` over the requests, or ``nan`` — "stamp me
+        with my row's clock", resolved per request in the scan fold.
+        (``-inf`` cannot be the sentinel: a non-empty partial stamped
+        ``-inf`` would decay to nothing on merge.)"""
+        if t is None:
+            return jnp.full(shape, jnp.nan, jnp.float32)
+        return jnp.broadcast_to(jnp.asarray(t, jnp.float32), shape)
 
     @staticmethod
     def _scatter_parts(state, ids, parts, mode=None):
-        """One scatter per leaf.  Sum leaves scatter-add; bounds scatter
-        min/max — with unique ids each row sees exactly one partial, so this
+        """One scatter per leaf, combining as ``core.engine`` merges each
+        field — with unique ids each row sees exactly one partial, so this
         is the per-tenant merge with no association ambiguity.  ``mode``:
         the scatters' out-of-bounds rule (``"drop"`` skips padding rows)."""
-        add = lambda l, p: l.at[ids].add(p, mode=mode)  # noqa: E731
-        lower = state.lower.at[ids].min(parts.lower, mode=mode)
-        upper = state.upper.at[ids].max(parts.upper, mode=mode)
-        if isinstance(state, QuantizedSketchEngineState):
-            return QuantizedSketchEngineState(
-                qcos_acc=add(state.qcos_acc, parts.qcos_acc),
-                qsin_acc=add(state.qsin_acc, parts.qsin_acc),
-                weight_sum=add(state.weight_sum, parts.weight_sum),
-                lower=lower,
-                upper=upper,
-                count=add(state.count, parts.count),
+        method = {"sum": "add", "min": "min", "max": "max"}
+        return type(state)(
+            *(
+                getattr(l.at[ids], method[eng_mod._COMBINE[f]])(p, mode=mode)
+                for f, l, p in zip(state._fields, state, parts)
             )
-        return SketchEngineState(
-            cos_acc=add(state.cos_acc, parts.cos_acc),
-            sin_acc=add(state.sin_acc, parts.sin_acc),
-            weight_sum=add(state.weight_sum, parts.weight_sum),
-            lower=lower,
-            upper=upper,
-            count=add(state.count, parts.count),
         )
 
     @staticmethod
@@ -845,12 +670,7 @@ class FleetEngine:
             tid, part, ok = inp
             row = jax.tree_util.tree_map(lambda l: l[tid], st)
             if isinstance(part, eng_mod.DECAYED_STATE_TYPES):
-                stamp = jnp.where(
-                    jnp.isnan(part.stamp),
-                    jnp.where(jnp.isfinite(row.stamp), row.stamp, 0.0),
-                    part.stamp,
-                )
-                part = part._replace(stamp=stamp)
+                part = part._replace(stamp=eng_mod._tick(row.stamp, part.stamp))
             merged = eng_mod._merge_states(row, part)
             st = jax.tree_util.tree_map(
                 lambda l, m, r: l.at[tid].set(jnp.where(ok, m, r)),
@@ -861,7 +681,7 @@ class FleetEngine:
         state, _ = jax.lax.scan(fold, state, (ids, parts, valid))
         return state
 
-    def _mesh_ingest(self, state, tenant_ids, batches, weights, t):
+    def _mesh_ingest(self, state, host, x, aux, t, unique):
         """:meth:`ingest` on a mesh-sharded fleet.
 
         Shard s receives its own requests, in arrival order, padded to the
@@ -870,46 +690,28 @@ class FleetEngine:
         requests' operators from its own block and folds them into its own
         rows: one shard_map, no collective.
         """
-        if isinstance(tenant_ids, jax.core.Tracer):
+        if host is None:
             raise ValueError(
                 "mesh-sharded ingest routes requests to shards on the host; "
                 "tenant_ids must be concrete"
             )
-        ids = np.asarray(tenant_ids, np.int64)
         p, rows = self.tenant_shards, self.shard_rows
-        owned = [np.flatnonzero(ids // rows == s) for s in range(p)]
+        owned = [np.flatnonzero(host // rows == s) for s in range(p)]
         width = max(len(o) for o in owned)
         pick = np.zeros((p, width), np.int64)  # request index per slot
         local = np.full((p, width), rows, np.int32)  # row in the block
         for s, o in enumerate(owned):
             pick[s, : len(o)] = o
-            local[s, : len(o)] = ids[o] - s * rows
-        unique = len(np.unique(ids)) == len(ids)
+            local[s, : len(o)] = host[o] - s * rows
 
         def place(a):
             return jax.device_put(a, self._tenant_sharding)
 
-        x = jnp.asarray(batches, jnp.float32)
-        if self.quantized:
-            if weights is not None:
-                raise ValueError(
-                    "quantized fleet states accumulate unit-weight integer "
-                    "counts; per-point weights are not representable"
-                )
-            aux = self.dither  # (T, m), placed with its tenants
-        elif weights is None:
-            aux = place(jnp.ones(x.shape[:2], jnp.float32)[pick])
-        else:
-            aux = place(jnp.asarray(weights, jnp.float32)[pick])
+        if not self.quantized:  # the dither stack is placed already
+            aux = place(aux[pick])
         operands = (state, self._stacked_op, place(local), place(x[pick]), aux)
         if self.decay is not None:
-            # nan = "stamp me with my row's clock", as in the unsharded fold.
-            if t is None:
-                stamps = jnp.full(ids.shape, jnp.nan, jnp.float32)
-            else:
-                stamps = jnp.broadcast_to(
-                    jnp.asarray(t, jnp.float32), ids.shape
-                )
+            stamps = self._request_stamps(host.shape, t)
             operands += (place(stamps[pick]),)
         return self._mesh_ingest_fn(state, unique)(*operands)
 
@@ -918,43 +720,19 @@ class FleetEngine:
         engine and id pattern (unique ids scatter; duplicates scan)."""
         if unique in self._mesh_ingest_jit:
             return self._mesh_ingest_jit[unique]
-        from repro.utils import compat
-
-        quantized, decayed = self.quantized, self.decay is not None
-        rows = self.shard_rows
+        quantized, rows = self.quantized, self.shard_rows
 
         def body(st, op, local, x, aux, *stamps):
-            local, x = local[0], x[0]
-            valid = local < rows
-            safe = jnp.where(valid, local, 0)
-            gathered = jax.tree_util.tree_map(lambda l: l[safe], op)
-            if quantized:
-                parts = jax.vmap(self._tenant_qpart)(gathered, aux[safe], x)
-            else:
-                parts = jax.vmap(self._tenant_part)(gathered, x, aux[0])
-            if decayed:
-                parts = self._lift_parts(parts, stamps[0][0])
-                return self._scan_parts(st, safe, parts, valid)
-            if unique:
-                return self._scatter_parts(st, local, parts, mode="drop")
-            return self._scan_parts(st, safe, parts, valid)
+            # Each operand but the state, the operators and the dither stack
+            # arrives as this shard's (1, width, ...) block.
+            return self._ingest_body(
+                st, op, local[0], x[0], aux if quantized else aux[0],
+                *(s[0] for s in stamps), unique=unique, rows=rows,
+            )
 
-        row = jax.sharding.PartitionSpec(self.tenant_shard_axis)
-        in_specs = (
-            self._row_specs(state),
-            self._row_specs(self._stacked_op),
-            row,
-            row,
-            row,
-        ) + ((row,) if decayed else ())
-        fn = compat.shard_map(
-            body,
-            self.mesh,
-            in_specs=in_specs,
-            out_specs=self._row_specs(state),
-            check_vma=False,
+        self._mesh_ingest_jit[unique] = self._shard_mapped(
+            body, 5 + (self.decay is not None)
         )
-        self._mesh_ingest_jit[unique] = jax.jit(fn)
         return self._mesh_ingest_jit[unique]
 
     def decay_to(self, state, t):
@@ -966,11 +744,7 @@ class FleetEngine:
                 "decay_to requires a decay-enabled fleet "
                 "(FleetEngine(..., decay=gamma))"
             )
-        empty = self.init_state()
-        stamp = jnp.broadcast_to(
-            jnp.asarray(t, jnp.float32), (self.n_tenants,)
-        )
-        return eng_mod._merge_states(state, empty._replace(stamp=stamp))
+        return eng_mod._decay_to(state, self.init_state(), t)
 
     # -- tenant state surgery (evict / restore build on these) --------------
 
@@ -986,7 +760,9 @@ class FleetEngine:
 
     def reset_tenant(self, state, tenant: int):
         """Tenant's row back to the monoid identity (post-eviction hole)."""
-        identity = self.tenant_engine(tenant).init_state()
+        identity = eng_mod._identity(
+            self.n, self.m, quantized=self.quantized, decay=self.decay
+        )
         return self.set_tenant(state, tenant, identity)
 
     def merge_tenant(self, state, tenant: int, partial):
@@ -997,29 +773,9 @@ class FleetEngine:
             state, tenant, eng_mod._merge_states(row, partial)
         )
 
-    def finalize_tenant(self, state, tenant: int):
-        """Finalize ONE tenant — O(m), the decode-on-demand hot path (the
-        full-fleet :meth:`finalize` is O(T·m))."""
-        row = self.tenant_state(state, tenant)
-        if self.quantized:
-            self._check_capacity(state)
-            fin = (
-                eng_mod._finalize_decayed_quantized
-                if isinstance(row, DecayedQuantizedSketchEngineState)
-                else eng_mod._finalize_quantized
-            )
-            return fin(row, self.dither[tenant], self.bits)
-        return eng_mod._finalize_state(row)
-
     def state_bytes(self) -> int:
         """Resident bytes of the stacked fleet state (all T tenants)."""
-        state = self.init_state()
-        return int(
-            sum(
-                l.size * l.dtype.itemsize
-                for l in jax.tree_util.tree_leaves(state)
-            )
-        )
+        return eng_mod._state_nbytes(self.init_state())
 
     def __repr__(self) -> str:  # pragma: no cover - cosmetic
         q = f", bits={self.bits}" if self.quantized else ""

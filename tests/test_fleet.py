@@ -44,14 +44,18 @@ BACKENDS = ["xla", "pallas"]
 QUANTS = ["none", "1bit"]
 
 
-def _make_engine(backend="xla", quant="none", n_tenants=T, name="dense"):
+def _make_engine(
+    backend="xla", quant="none", n_tenants=T, name="dense", decay=None
+):
     specs = fl.fleet_specs(jax.random.PRNGKey(0), n_tenants, name, M, N, 1.5)
     quants = fl.fleet_quantizers(jax.random.PRNGKey(7), n_tenants, M, quant)
     kwargs = {}
     if backend == "pallas":
         # Tiny blocks + interpret so the kernel path runs off-TPU in tests.
         kwargs = dict(block_n=32, block_m=32, interpret=True)
-    return fl.FleetEngine(specs, backend=backend, quantizers=quants, **kwargs)
+    return fl.FleetEngine(
+        specs, backend=backend, quantizers=quants, decay=decay, **kwargs
+    )
 
 
 def _batches(key, rounds=1, n_tenants=T, batch=B):
@@ -85,22 +89,25 @@ def _cheap_decode_cfg(**overrides):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("quant", QUANTS)
-def test_vmapped_monoid_parity(backend, quant):
+@pytest.mark.parametrize("decay", [None, 0.9])
+def test_vmapped_monoid_parity(backend, quant, decay):
     """Stacked update/merge/finalize == Python loop of SketchEngine calls,
-    bitwise, for every tenant."""
-    eng = _make_engine(backend, quant)
+    bitwise, for every tenant — every state flavour, each round at its own
+    tick under decay, so the merge decays the older round."""
+    eng = _make_engine(backend, quant, decay=decay)
     xs = _batches(jax.random.PRNGKey(1), rounds=2)
+    ticks = [{}, {}] if decay is None else [dict(t=1.0), dict(t=3.0)]
 
     # Stacked path: two update rounds into two states, then a merge.
-    sa = eng.update(eng.init_state(), xs[0])
-    sb = eng.update(eng.init_state(), xs[1])
+    sa = eng.update(eng.init_state(), xs[0], **ticks[0])
+    sb = eng.update(eng.init_state(), xs[1], **ticks[1])
     merged = eng.merge(sa, sb)
     z, lo, hi = eng.finalize(merged)
 
     for t in range(T):
         ref_eng = eng.tenant_engine(t)
-        ra = ref_eng.update(ref_eng.init_state(), xs[0, t])
-        rb = ref_eng.update(ref_eng.init_state(), xs[1, t])
+        ra = ref_eng.update(ref_eng.init_state(), xs[0, t], **ticks[0])
+        rb = ref_eng.update(ref_eng.init_state(), xs[1, t], **ticks[1])
         rm = ref_eng.merge(ra, rb)
         assert _rows_equal(eng.tenant_state(sa, t), ra)
         assert _rows_equal(eng.tenant_state(merged, t), rm)

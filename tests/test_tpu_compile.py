@@ -129,7 +129,7 @@ CASES = {
         [(N_PTS, FEAT), (K, FEAT)],
     ),
     # The fleet's pallas update: the per-tenant sketch vmapped over one
-    # request per tenant (core.fleet.FleetEngine._tenant_part).
+    # request per tenant (core.engine._batch_partial under FleetEngine._parts).
     "fleet_fourier_sketch": (
         jax.vmap(
             lambda w, x, b: ops.fourier_sketch_sums(
